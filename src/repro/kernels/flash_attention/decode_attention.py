@@ -5,10 +5,11 @@ the KV axis — the paper's blocked-loop-nest story applied to the serve hot
 loop.  One query token per (slot, kv head) attends over a ragged prefix of
 the slot's KV cache:
 
-  * grid ``(batch * kv_heads, kv_splits)``: rows are independent; the KV
-    split axis is innermost and sequential, so the online-softmax partials
-    (running max / normalizer / fp32 accumulator) live in VMEM scratch and
-    are combined across splits without materializing per-split outputs.
+  * grid ``(batch, kv_splits)``: rows are independent; the KV split axis
+    is innermost and sequential, so the online-softmax partials (running
+    max / normalizer / fp32 accumulator, one set per kv head) live in VMEM
+    scratch and are combined across splits without materializing
+    per-split outputs.
   * per-row KV **lengths are a scalar-prefetch operand** (SMEM, available
     before the body runs): a traced ``(B,)`` int32, so lengths changing
     every decode step never recompiles, and the k/v index maps alias every
@@ -16,9 +17,13 @@ the slot's KV cache:
     block indices elide the HBM->VMEM copy, so each slot only *reads*
     ``ceil(len/bk)`` KV blocks.  Dead blocks also skip compute via
     ``pl.when``.
-  * GQA is resolved **inside** the kernel: q rows are ``(G, d)`` groups and
-    the k/v index maps divide the row id by ``kv_heads`` — KV tiles are
-    fetched once per kv head, never broadcast G-fold beforehand.
+  * GQA is resolved **inside** the kernel: a K/V tile is ``(bk, KV, d)``
+    — every kv head of one split — and a static loop over heads scores
+    each head's ``(G, d)`` query group against its own ``(bk, d)`` slice,
+    so KV is fetched once and never broadcast G-fold beforehand.  Tiling
+    all heads at once is also what the TPU compiler demands: a block's
+    last two dims must be (8, 128)-divisible or equal to the array's, and
+    ``(1, d)`` against ``(KV, d)`` is neither.
 
 k/v come in the serve engine's native cache layout ``(B, S, KV, d)`` so the
 donated decode loop hands the ring buffers to the kernel with zero copies.
@@ -45,7 +50,7 @@ Paged variants (:func:`flash_decode_paged_pallas`,
 ``(num_blocks, block_size, KV, d)`` instead of a dense per-slot axis, and
 each row carries a **block table** ``(B, max_blocks)`` mapping its logical
 block ``j`` to a physical pool block.  The grid stays
-``(batch * kv_heads, kv_splits)`` with ``kv_splits == max_blocks``; the
+``(batch, kv_splits)`` with ``kv_splits == max_blocks``; the
 only change is that the k/v index maps go through the table — a second
 scalar-prefetch operand — so block-table *contents* never recompile, and
 dead splits alias to the row's last live **physical** block exactly like
@@ -76,18 +81,31 @@ from repro.kernels.flash_attention.flash_attention import (
 )
 
 
-def _decode_kernel(
-    lens_ref,                     # SMEM (B,) int32 scalar-prefetch
-    q_ref,                        # (1, G, d)
-    k_ref,                        # (1, bk, 1, d)
-    v_ref,                        # (1, bk, 1, d)
-    o_ref,                        # (1, G, d)
-    m_ref, l_ref, acc_ref,        # VMEM scratch: (G,), (G,), (G, d) fp32
-    *, kv_heads: int, bk: int, n_k: int, scale: float,
+def _live_keys(k_idx, length, window):
+    """Live-key predicate of one decode row: logical index < length, plus
+    the paged kernel's optional sliding window against the query position
+    ``length - 1`` (logical index == absolute position in the paged
+    layout)."""
+    ok = k_idx < length
+    if window is not None:
+        ok &= k_idx > length - 1 - window
+    return ok
+
+
+def _decode_step(
+    length,                       # live keys of this row (SMEM scalar)
+    q_ref,                        # (1, KV, G, d)
+    k_ref,                        # (1, bk, KV, d)
+    v_ref,                        # (1, bk, KV, d)
+    o_ref,                        # (1, KV, G, d)
+    m_ref, l_ref, acc_ref,        # VMEM scratch: (KV, G), (KV, G), (KV, G, d) fp32
+    *, bk: int, n_k: int, scale: float, window: int | None,
 ):
-    bh = pl.program_id(0)
+    """One KV split of one row, shared by the dense and paged kernels.  The
+    K/V block carries every kv head (its last two dims are the array's
+    ``(KV, d)``, which is what the TPU block-shape rule accepts for any KV);
+    a static loop over heads runs the online-softmax update per head."""
     j = pl.program_id(1)
-    length = lens_ref[bh // kv_heads]
 
     @pl.when(j == 0)
     def _init():
@@ -95,28 +113,43 @@ def _decode_kernel(
 
     @pl.when(j * bk < length)
     def _live():
-        q = q_ref[0]                      # (G, d)
-        k = k_ref[0, :, 0, :]             # (bk, d)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                         # (G, bk)
-        k_idx = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(k_idx < length, s, NEG_INF)
+        for h in range(q_ref.shape[1]):     # static: one pass per kv head
+            q = q_ref[0, h]                   # (G, d)
+            k = k_ref[0, :, h, :]             # (bk, d)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                         # (G, bk)
+            k_idx = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(_live_keys(k_idx, length, window), s, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-        m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, :, 0, :],
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-        )
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+            p = jnp.exp(s - m_new[:, None])
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1)
+            m_ref[h] = m_new
+            acc_ref[h] = acc_ref[h] * corr[:, None] + jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0, :, h, :],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            )
 
     @pl.when(j == n_k - 1)
     def _store():
         finalize_out(o_ref, l_ref, acc_ref)
+
+
+def _decode_scratch(KV: int, G: int, d: int) -> list:
+    return [
+        pltpu.VMEM((KV, G), jnp.float32),
+        pltpu.VMEM((KV, G), jnp.float32),
+        pltpu.VMEM((KV, G, d), jnp.float32),
+    ]
+
+
+def _decode_kernel(lens_ref, *refs, **kw):
+    # lens_ref: SMEM (B,) int32 scalar-prefetch
+    _decode_step(lens_ref[pl.program_id(0)], *refs, window=None, **kw)
 
 
 def flash_decode_pallas(
@@ -135,35 +168,32 @@ def flash_decode_pallas(
     scale = 1.0 / math.sqrt(d)
     lengths = jnp.clip(lengths.astype(jnp.int32), 1, S)
 
-    def kv_block(bh, j, lens):
-        last = last_live_block(lens[bh // KV], bk)
-        return (bh // KV, jnp.minimum(j, last), bh % KV, 0)
+    def kv_block(b, j, lens):
+        return (b, jnp.minimum(j, last_live_block(lens[b], bk)), 0, 0)
+
+    def row(b, j, lens):
+        return (b, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B * KV, n_k),
+        grid=(B, n_k),
         in_specs=[
-            pl.BlockSpec((1, G, d), lambda bh, j, lens: (bh, 0, 0)),
-            pl.BlockSpec((1, bk, 1, d), kv_block),
-            pl.BlockSpec((1, bk, 1, d), kv_block),
+            pl.BlockSpec((1, KV, G, d), row),
+            pl.BlockSpec((1, bk, KV, d), kv_block),
+            pl.BlockSpec((1, bk, KV, d), kv_block),
         ],
-        out_specs=pl.BlockSpec((1, G, d), lambda bh, j, lens: (bh, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, d), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, KV, G, d), row),
+        scratch_shapes=_decode_scratch(KV, G, d),
     )
     kern = functools.partial(
-        _decode_kernel, kv_heads=KV, bk=bk, n_k=n_k, scale=scale,
+        _decode_kernel, bk=bk, n_k=n_k, scale=scale,
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * KV, G, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, d), q.dtype),
         interpret=interpret,
-    )(lengths, q.reshape(B * KV, G, d), k, v)
-    return out.reshape(B, KV, G, d)
+    )(lengths, q, k, v)
 
 
 def decode_attention_xla(
@@ -217,58 +247,10 @@ def decode_attention_xla(
 # ------------------------------------------------------------ paged variants
 
 
-def _paged_live(k_idx, length, window):
-    """Live-key predicate for paged decode: logical index < length, plus an
-    optional sliding window against the query position ``length - 1``
-    (logical index == absolute position in the paged layout)."""
-    ok = k_idx < length
-    if window is not None:
-        ok &= k_idx > length - 1 - window
-    return ok
-
-
-def _paged_decode_kernel(
-    lens_ref,                     # SMEM (B,) int32 scalar-prefetch
-    table_ref,                    # SMEM (B, n_blk) int32 scalar-prefetch
-    q_ref,                        # (1, G, d)
-    k_ref,                        # (1, bs, 1, d) one physical pool block
-    v_ref,                        # (1, bs, 1, d)
-    o_ref,                        # (1, G, d)
-    m_ref, l_ref, acc_ref,        # VMEM scratch: (G,), (G,), (G, d) fp32
-    *, kv_heads: int, bs: int, n_blk: int, scale: float, window: int | None,
-):
-    bh = pl.program_id(0)
-    j = pl.program_id(1)
-    length = lens_ref[bh // kv_heads]
-
-    @pl.when(j == 0)
-    def _init():
-        reset_carry(m_ref, l_ref, acc_ref)
-
-    @pl.when(j * bs < length)
-    def _live():
-        q = q_ref[0]                      # (G, d)
-        k = k_ref[0, :, 0, :]             # (bs, d)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                         # (G, bs)
-        k_idx = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(_paged_live(k_idx, length, window), s, NEG_INF)
-
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-        m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, :, 0, :],
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(j == n_blk - 1)
-    def _store():
-        finalize_out(o_ref, l_ref, acc_ref)
+def _paged_decode_kernel(lens_ref, table_ref, *refs, **kw):
+    # lens_ref: SMEM (B,) int32; table_ref: SMEM (B, n_blk) int32 — both
+    # scalar-prefetch; the table is only read by the index maps
+    _decode_step(lens_ref[pl.program_id(0)], *refs, **kw)
 
 
 def flash_decode_paged_pallas(
@@ -288,37 +270,34 @@ def flash_decode_paged_pallas(
     lengths = jnp.clip(lengths.astype(jnp.int32), 1, n_blk * bs)
     tables = tables.astype(jnp.int32)
 
-    def kv_block(bh, j, lens, tabs):
-        b = bh // KV
+    def kv_block(b, j, lens, tabs):
         last = last_live_block(lens[b], bs)
-        return (tabs[b, jnp.minimum(j, last)], 0, bh % KV, 0)
+        return (tabs[b, jnp.minimum(j, last)], 0, 0, 0)
+
+    def row(b, j, lens, tabs):
+        return (b, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B * KV, n_blk),
+        grid=(B, n_blk),
         in_specs=[
-            pl.BlockSpec((1, G, d), lambda bh, j, lens, tabs: (bh, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d), kv_block),
-            pl.BlockSpec((1, bs, 1, d), kv_block),
+            pl.BlockSpec((1, KV, G, d), row),
+            pl.BlockSpec((1, bs, KV, d), kv_block),
+            pl.BlockSpec((1, bs, KV, d), kv_block),
         ],
-        out_specs=pl.BlockSpec((1, G, d), lambda bh, j, lens, tabs: (bh, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, d), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, KV, G, d), row),
+        scratch_shapes=_decode_scratch(KV, G, d),
     )
     kern = functools.partial(
         _paged_decode_kernel,
-        kv_heads=KV, bs=bs, n_blk=n_blk, scale=scale, window=window,
+        bk=bs, n_k=n_blk, scale=scale, window=window,
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * KV, G, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, d), q.dtype),
         interpret=interpret,
-    )(lengths, tables, q.reshape(B * KV, G, d), kpool, vpool)
-    return out.reshape(B, KV, G, d)
+    )(lengths, tables, q, kpool, vpool)
 
 
 def decode_attention_paged_xla(
@@ -352,7 +331,7 @@ def decode_attention_paged_xla(
             "bhgd,bshd->bhgs", q, kb, preferred_element_type=jnp.float32
         ) * scale                                       # (B, KV, G, bs)
         k_idx = j * bs + jnp.arange(bs, dtype=jnp.int32)
-        live = _paged_live(k_idx[None, :], lengths[:, None], window)
+        live = _live_keys(k_idx[None, :], lengths[:, None], window)
         s = jnp.where(live[:, None, None, :], s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[..., None])

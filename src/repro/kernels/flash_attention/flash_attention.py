@@ -48,7 +48,7 @@ def reset_carry(m_ref, l_ref, acc_ref):
 def finalize_out(o_ref, l_ref, acc_ref):
     """Normalize the accumulator into the output block at the last step."""
     o_ref[0, ...] = (
-        acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
+        acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[..., None]
     ).astype(o_ref.dtype)
 
 

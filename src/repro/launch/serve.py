@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.arch.model_zoo import build
 from repro.configs.registry import get
+from repro.launch.cache import enable_compile_cache
 from repro.serve.engine import (
     DurabilityConfig,
     Engine,
@@ -163,6 +164,7 @@ def main():
         ap.error("--abft localizes corruption through the paged pool's "
                  "per-block fingerprints (add --kv-layout paged)")
 
+    enable_compile_cache()
     cfg = get(args.arch)
     model = build(cfg)
     params = model.init(jax.random.PRNGKey(0))

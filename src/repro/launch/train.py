@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.configs.registry import get
 from repro.data.pipeline import DataConfig, Pipeline
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_train_step
 from repro.parallel import policy
@@ -40,6 +41,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=10)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get(args.arch)
     mesh = make_host_mesh()
     plan = ShardingPlan(mesh)

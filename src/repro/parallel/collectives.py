@@ -17,7 +17,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 BLOCK = 256
@@ -69,7 +68,7 @@ def compressed_psum(
         return deq.reshape(-1)[:n].reshape(xs.shape)
 
     specs = P(*([None] * x.ndim))
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh, in_specs=(specs,), out_specs=specs,
         check_vma=False,
     )

@@ -443,12 +443,13 @@ class DurabilityConfig:
     # legally wrote quarantines every request referencing it (FAILED,
     # blocks released).  O(pool) device work per step — off by default.
     kv_checksum: bool = False
-    # one-shot kernel-failure fallback: if the jitted decode path raises
-    # (Pallas lowering/compile failure on an exotic backend), rebuild it on
-    # the oracle substrate (flash -> masked xla; paged -> gather twin) with
-    # a logged warning instead of dying.  Greedy outputs are substrate-
-    # independent (tests pin this), so serving continues bitwise-intact.
-    substrate_fallback: bool = True
+    # opt-in one-shot kernel-failure fallback: if the jitted decode path
+    # raises (Pallas lowering/compile failure on an exotic backend),
+    # rebuild it on the oracle substrate (flash -> masked xla; paged ->
+    # gather twin) with a logged warning instead of dying.  Off by default:
+    # a kernel failure is fatal, so a served run never hides that its
+    # decode kernel did not run (stats["fallbacks"] counts the opt-in).
+    substrate_fallback: bool = False
 
     def __post_init__(self):
         if self.snapshot_every < 1:
@@ -1202,9 +1203,10 @@ class Engine:
         return jax.jit(decode_fn, donate_argnums=(2,))
 
     def _decode_call(self, *args):
-        """Run the decode program, falling back ONCE to the oracle
-        substrate on failure (flash -> masked xla attend; paged -> the
-        gather twin, both reached by rebuilding with ``attn=None``).
+        """Run the decode program.  A failure is fatal unless the caller
+        opted into ``substrate_fallback``: then it falls back ONCE to the
+        oracle substrate (flash -> masked xla attend; paged -> the gather
+        twin, both reached by rebuilding with ``attn=None``).
         Pallas kernel failures surface at trace/compile time — before the
         donated caches are consumed — so the retry sees intact buffers."""
         try:

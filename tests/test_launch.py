@@ -130,3 +130,33 @@ def test_dryrun_single_cell_subprocess(tmp_path):
     )
     assert rec["cost_per_device"]["flops"] > 0
     assert rec["memory"]["peak_estimate_bytes"] < 16 * 1024**3
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """The entry points' compile cache honours $JAX_COMPILATION_CACHE_DIR
+    and otherwise sits at one fixed, git-ignored path of the checkout."""
+    from pathlib import Path
+
+    from repro.launch.cache import compile_cache_dir, enable_compile_cache
+
+    env = {"JAX_COMPILATION_CACHE_DIR": str(tmp_path)}
+    assert compile_cache_dir(env) == tmp_path
+    default = compile_cache_dir({})
+    assert default == compile_cache_dir({}) == Path(REPO) / ".cache" / "jax"
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".cache/" in f.read().split()
+
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        # set: JAX reads the variable itself, the entry point sets nothing
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == tmp_path
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == default
+        assert jax.config.jax_compilation_cache_dir == str(default)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+        cc.reset_cache()

@@ -332,7 +332,7 @@ def test_kv_checksum_detects_finite_corruption(smol, paged_oracle):
 def test_substrate_fallback_is_one_shot(smol, paged_oracle):
     cfg, params = smol
     reqs, want = paged_oracle
-    eng = Engine(cfg, params, _paged())
+    eng = Engine(cfg, params, _paged(substrate_fallback=True))
     calls = {"n": 0}
 
     def boom(*args):
