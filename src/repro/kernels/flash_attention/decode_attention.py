@@ -193,6 +193,7 @@ def flash_decode_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, d), q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(lengths, q, k, v)
 
 
@@ -297,6 +298,7 @@ def flash_decode_paged_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, d), q.dtype),
         interpret=interpret,
+        name="decode_attention_paged",
     )(lengths, tables, q, kpool, vpool)
 
 

@@ -75,6 +75,19 @@ like ``kernels/matmul/ops.py`` — enable with ``ServeConfig(matmul="pallas")``.
 The pre-continuous static-batch loop survives as :class:`StaticEngine`, the
 baseline that ``benchmarks/serve_bench.py`` measures against; it follows the
 same ``attention`` setting so the A/B isolates scheduling.
+
+Tracing.  :meth:`Engine.step` and :meth:`Engine.submit` annotate the
+profiler's trace with ``jax.profiler.TraceAnnotation`` spans named
+``serve.*``, on the same clock as the device's operations.  ``serve.step``
+is tiled, in order, by ``serve.schedule`` (deadlines, preemption,
+admission, copy-on-write, building and uploading the decode inputs; its
+children are ``serve.lane.start``/``.chunk``/``.install``, ``serve.admit``
+and ``serve.cow``), ``serve.decode`` (the decode dispatch up to its tokens
+on the host) and ``serve.emit`` (quarantine, token callbacks, finishing and
+eviction), then ``serve.journal`` when a RecoveryManager is attached.  A
+step with no live slot after admission stops after ``serve.schedule``.
+With no trace running a span costs a native check and a small Python
+object.
 """
 
 from __future__ import annotations
@@ -94,6 +107,8 @@ from repro.arch import layers as L
 from repro.arch.model_zoo import build
 from repro.configs.base import ModelConfig
 from repro.serve import kvcache
+
+_span = jax.profiler.TraceAnnotation
 
 # on_token(request_id, token, index, done)
 TokenCallback = Callable[[int, int, int, bool], None]
@@ -824,6 +839,12 @@ class _PagedRow:
     tail_shared: bool            # partial prompt tail aliased (CoW pending)
     cow_dst: int | None          # pre-allocated CoW target for the tail
 
+    def hit_tokens(self, block_size: int) -> int:
+        """Prompt tokens aliased through the prefix index (at commit,
+        before copy-on-write privatises the tail)."""
+        tail = self.plen % block_size if self.tail_shared else 0
+        return self.n_shared_full * block_size + tail
+
 
 @dataclasses.dataclass
 class _PrefillLane:
@@ -945,6 +966,8 @@ class Engine:
             "snapshots": 0,     # recovery snapshots staged
             "sdc_detected": 0,  # abft: steps whose checksums flagged
             "sdc_retried": 0,   # abft: oracle-substrate step re-executions
+            "prompt_tokens": 0,      # paged: prompt tokens of committed admissions
+            "prefix_hit_tokens": 0,  # ... of them aliased through the prefix index
         }
 
         model, impl, axes = self.model, self._impl, self._axes
@@ -1244,6 +1267,10 @@ class Engine:
         queue (``ServeConfig.max_waiting``) REJECTs the submission instead
         of raising — poll :meth:`status` / :meth:`pop_result`."""
         rid = req.request_id if req.request_id is not None else self._next_rid
+        with _span("serve.submit", rid=rid):
+            return self._submit(req, rid)
+
+    def _submit(self, req: Request, rid: int) -> int:
         if rid in self._reqs:
             raise ValueError(f"duplicate request_id {rid}")
         if req.deadline_steps is not None and req.deadline_steps < 0:
@@ -1397,13 +1424,13 @@ class Engine:
             keys[j], tlens[j] = info.key, len(info.prompt)
         return toks, keys, tlens
 
-    def _admit_waiting(self, on_token: TokenCallback | None) -> bool:
+    def _admit_waiting(self, on_token: TokenCallback | None) -> int:
         """Backfill every free slot from the queue.  Admissions sharing a
         prefill length run as ONE fused jitted call (prefill + tail mask +
         slot scatter + first-token sample); right-padding to
         ``prefill_bucket`` collapses mixed prompt lengths onto one compiled
         shape where that is exact (`kvcache.supports_padded_prefill`).
-        Returns True when anything was admitted."""
+        Returns how many requests were admitted."""
         if self._paged:
             return self._admit_waiting_paged(on_token)
         groups: dict[int, list[tuple[_ReqInfo, int]]] = {}
@@ -1429,10 +1456,10 @@ class Engine:
             for j, (info, slot) in enumerate(items):
                 self._activate(info, slot, int(toks0[j]), on_token)
         self.stats["peak_active"] = max(self.stats["peak_active"], len(self._slots))
-        return bool(groups)
+        return sum(len(items) for items in groups.values())
 
     # ------------------------------------------------------ paged admission --
-    def _admit_waiting_paged(self, on_token: TokenCallback | None) -> bool:
+    def _admit_waiting_paged(self, on_token: TokenCallback | None) -> int:
         """Paged admission: a request enters when a slot AND enough free
         blocks are available (strict order over (-priority, arrival) — the
         queue head never gets jumped).  Ownership is committed host-side
@@ -1494,7 +1521,7 @@ class Engine:
                     )
                 self._activate(info, slot, int(toks0[j]), on_token)
         self.stats["peak_active"] = max(self.stats["peak_active"], len(self._slots))
-        return bool(groups)
+        return sum(len(items) for items in groups.values())
 
     def _commit_row(self, info: _ReqInfo) -> _PagedRow | None:
         """Host-side block ownership for one paged admission: retain prefix
@@ -1526,13 +1553,16 @@ class Engine:
         while len(blocks) < total:
             blocks.append(self.pool.alloc())
         cow_dst = self.pool.alloc() if cow_needed else None
-        return _PagedRow(
+        row = _PagedRow(
             blocks=blocks,
             plen=plen,
             n_shared_full=len(shared_full),
             tail_shared=shared_tail is not None,
             cow_dst=cow_dst,
         )
+        self.stats["prompt_tokens"] += plen
+        self.stats["prefix_hit_tokens"] += row.hit_tokens(bs)
+        return row
 
     def _register_chain(self, info: _ReqInfo, row: _PagedRow) -> None:
         """Publish this row's prompt blocks in the radix prefix index.
@@ -1562,16 +1592,18 @@ class Engine:
         if self._lane is not None or not self._waiting or not self._free:
             return False
         info = self._reqs[self._waiting[0]]
-        row = None
-        if self._paged:
-            row = self._commit_row(info)
-            if row is None:
-                return False
-        self._waiting.pop(0)
-        slot = self._free.popleft()
-        info.status = RequestStatus.PREFILLING
-        self._scratch = self._fresh_scratch()
-        self._lane = _PrefillLane(rid=info.rid, slot=slot, row=row)
+        with _span("serve.lane.start", rid=info.rid) as span:
+            row = None
+            if self._paged:
+                row = self._commit_row(info)
+                if row is None:
+                    return False
+                span.set_metadata(hit_tokens=row.hit_tokens(self.scfg.block_size))
+            self._waiting.pop(0)
+            slot = self._free.popleft()
+            info.status = RequestStatus.PREFILLING
+            self._scratch = self._fresh_scratch()
+            self._lane = _PrefillLane(rid=info.rid, slot=slot, row=row)
         return True
 
     def _advance_lane(self):
@@ -1585,16 +1617,17 @@ class Engine:
         C = self._chunk
         plen = len(info.prompt)
         end = min(plen, lane.filled + C)
-        toks = np.zeros((1, C), np.int32)
-        toks[0, : end - lane.filled] = info.prompt[lane.filled : end]
-        li = np.asarray([min(C - 1, max(0, plen - 1 - lane.filled))], np.int32)
-        tok0, self._scratch = self._chunk_step(
-            self.params,
-            jnp.asarray(toks),
-            self._scratch,
-            jnp.asarray(li),
-            jnp.asarray(info.key),
-        )
+        with _span("serve.lane.chunk", rid=lane.rid, filled=end):
+            toks = np.zeros((1, C), np.int32)
+            toks[0, : end - lane.filled] = info.prompt[lane.filled : end]
+            li = np.asarray([min(C - 1, max(0, plen - 1 - lane.filled))], np.int32)
+            tok0, self._scratch = self._chunk_step(
+                self.params,
+                jnp.asarray(toks),
+                self._scratch,
+                jnp.asarray(li),
+                jnp.asarray(info.key),
+            )
         lane.filled = end
         return end >= plen, tok0
 
@@ -1607,46 +1640,48 @@ class Engine:
         monolithically admitted request."""
         lane = self._lane
         self._lane = None
-        info = self._reqs[lane.rid]
-        plen = len(info.prompt)
-        slot = lane.slot
-        if self._paged:
-            row = lane.row
-            bs = self.scfg.block_size
-            n_blk = self.scfg.max_len // bs
-            table_row = np.full((n_blk,), kvcache.SINK_BLOCK, np.int32)
-            table_row[: len(row.blocks)] = row.blocks
-            self.caches = self._set_row(
-                self.caches,
-                jnp.int32(slot),
-                jnp.asarray(table_row),
-                jnp.int32(plen),
-            )
-            n_prompt = -(-plen // bs)
-            start = row.n_shared_full
-            n_pack = n_prompt - start - (1 if row.tail_shared else 0)
-            if n_pack > 0:
-                self.caches = self._pack_row(
+        with _span("serve.lane.install", rid=lane.rid) as span:
+            info = self._reqs[lane.rid]
+            plen = len(info.prompt)
+            slot = lane.slot
+            if self._paged:
+                row = lane.row
+                bs = self.scfg.block_size
+                n_blk = self.scfg.max_len // bs
+                table_row = np.full((n_blk,), kvcache.SINK_BLOCK, np.int32)
+                table_row[: len(row.blocks)] = row.blocks
+                self.caches = self._set_row(
                     self.caches,
-                    {"k": self._scratch["k"], "v": self._scratch["v"]},
-                    jnp.int32(0),
-                    jnp.int32(start),
-                    jnp.asarray(row.blocks[start : start + n_pack], jnp.int32),
+                    jnp.int32(slot),
+                    jnp.asarray(table_row),
+                    jnp.int32(plen),
                 )
-            self._register_chain(info, row)
-            self._rows[slot] = row
-            if self._kv_sums is not None:
-                self._touched.update(row.blocks)
-        else:
-            self.caches = self._install_slot(
-                self.caches,
-                self._scratch,
-                jnp.int32(slot),
-                jnp.asarray([plen], jnp.int32),
-            )
-        self.stats["admitted"] += 1
-        self._activate(info, slot, int(np.asarray(tok0)[0]), on_token)
-        self.stats["peak_active"] = max(self.stats["peak_active"], len(self._slots))
+                n_prompt = -(-plen // bs)
+                start = row.n_shared_full
+                n_pack = n_prompt - start - (1 if row.tail_shared else 0)
+                span.set_metadata(packed=max(n_pack, 0))
+                if n_pack > 0:
+                    self.caches = self._pack_row(
+                        self.caches,
+                        {"k": self._scratch["k"], "v": self._scratch["v"]},
+                        jnp.int32(0),
+                        jnp.int32(start),
+                        jnp.asarray(row.blocks[start : start + n_pack], jnp.int32),
+                    )
+                self._register_chain(info, row)
+                self._rows[slot] = row
+                if self._kv_sums is not None:
+                    self._touched.update(row.blocks)
+            else:
+                self.caches = self._install_slot(
+                    self.caches,
+                    self._scratch,
+                    jnp.int32(slot),
+                    jnp.asarray([plen], jnp.int32),
+                )
+            self.stats["admitted"] += 1
+            self._activate(info, slot, int(np.asarray(tok0)[0]), on_token)
+            self.stats["peak_active"] = max(self.stats["peak_active"], len(self._slots))
 
     def _drop_lane(self) -> None:
         """Release a mid-flight lane's resources.  No device writes are
@@ -1708,25 +1743,30 @@ class Engine:
         """Before rows write: give every slot still aliasing a shared
         prompt-tail block its pre-reserved private copy (first divergent
         write is about to land at ``plen``, inside that block)."""
-        for slot in sorted(self._slots):
-            row = self._rows.get(slot)
-            if row is None or row.cow_dst is None:
-                continue
-            lb = row.plen // self.scfg.block_size
-            src = row.blocks[lb]
-            self.caches = self._cow(
-                self.caches,
-                jnp.int32(slot),
-                jnp.int32(lb),
-                jnp.int32(src),
-                jnp.int32(row.cow_dst),
-            )
-            self.pool.release(src)
-            if self._kv_sums is not None:
-                self._touched.add(row.cow_dst)
-            row.blocks[lb] = row.cow_dst
-            row.cow_dst = None
-            row.tail_shared = False
+        pending = [
+            (slot, self._rows[slot])
+            for slot in sorted(self._slots)
+            if slot in self._rows and self._rows[slot].cow_dst is not None
+        ]
+        if not pending:
+            return
+        with _span("serve.cow", n=len(pending)):
+            for slot, row in pending:
+                lb = row.plen // self.scfg.block_size
+                src = row.blocks[lb]
+                self.caches = self._cow(
+                    self.caches,
+                    jnp.int32(slot),
+                    jnp.int32(lb),
+                    jnp.int32(src),
+                    jnp.int32(row.cow_dst),
+                )
+                self.pool.release(src)
+                if self._kv_sums is not None:
+                    self._touched.add(row.cow_dst)
+                row.blocks[lb] = row.cow_dst
+                row.cow_dst = None
+                row.tail_shared = False
 
     def _evict_paged(self, slot: int) -> None:
         """Release a finished/cancelled/preempted row: repoint its device
@@ -2021,143 +2061,152 @@ class Engine:
         step's emitted-token deltas are journaled (and a snapshot staged on
         cadence) before control returns — the crash-durability boundary is
         the end of every step."""
-        alive = self._step_core(on_token)
-        if self.recovery is not None:
-            self.recovery.after_step()
+        with _span("serve.step", step=self._step_no + 1):
+            alive = self._step_core(on_token)
+            if self.recovery is not None:
+                with _span("serve.journal"):
+                    self.recovery.after_step()
         return alive
 
     def _step_core(self, on_token: TokenCallback | None) -> bool:
-        if self._abft and self._kv_sums is not None:
-            # audit BEFORE decode, against the blocks the PREVIOUS step
-            # legally wrote: an inter-step KV flip quarantines its owner
-            # before the poisoned attention read, so the victim's partial
-            # output stays a clean oracle prefix and survivors never see
-            # the corrupt block
-            self._audit_kv_checksums()
-        self._step_no += 1
-        self._touched = {kvcache.SINK_BLOCK}
-        self._expire_deadlines()
-        self._preempt_pass()
-        admitted = False
-        if self._chunk:
-            admitted = self._schedule_chunks(on_token)
-        else:
-            while self._free and self._waiting:
-                if not self._admit_waiting(on_token):
-                    break  # paged: head of queue waits for free blocks
-                admitted = True
-        if self._paged:
-            self._resolve_cow()
-        if not self._slots:
-            if self._lane is not None:
-                # a mid-flight prefill lane IS progress: decode has nothing
-                # to do yet, but the engine is anything but idle
-                self._stalled = 0
-                return True
-            if not self._waiting:
-                self._stalled = 0
-                return False
-            if admitted:
-                # budget-1 admissions finished instantly: that is progress
-                self._stalled = 0
+        with _span("serve.schedule"):
+            if self._abft and self._kv_sums is not None:
+                # audit BEFORE decode, against the blocks the PREVIOUS step
+                # legally wrote: an inter-step KV flip quarantines its owner
+                # before the poisoned attention read, so the victim's partial
+                # output stays a clean oracle prefix and survivors never see
+                # the corrupt block
+                self._audit_kv_checksums()
+            self._step_no += 1
+            self._touched = {kvcache.SINK_BLOCK}
+            self._expire_deadlines()
+            self._preempt_pass()
+            admitted = False
+            if self._chunk:
+                admitted = self._schedule_chunks(on_token)
             else:
-                # zero active slots, zero admissions, a non-empty queue:
-                # nothing inside the engine can free capacity.  Shed the
-                # head after `stall_patience` such steps instead of
-                # spinning forever on externally-held or leaked blocks.
-                self._stalled += 1
-                if self._stalled >= self.scfg.stall_patience:
-                    info = self._reqs[self._waiting.pop(0)]
-                    self.stats["shed"] += 1
-                    self._finish(
-                        info,
-                        RequestStatus.REJECTED,
-                        f"shed by watchdog: no admission progress in "
-                        f"{self._stalled} idle steps",
-                    )
+                while self._free and self._waiting:
+                    with _span("serve.admit") as span:
+                        n = self._admit_waiting(on_token)
+                        span.set_metadata(n=n)
+                    if not n:
+                        break  # paged: head of queue waits for free blocks
+                    admitted = True
+            if self._paged:
+                self._resolve_cow()
+            if not self._slots:
+                if self._lane is not None:
+                    # a mid-flight prefill lane IS progress: decode has nothing
+                    # to do yet, but the engine is anything but idle
                     self._stalled = 0
-            return bool(self._waiting)
-        self._stalled = 0
+                    return True
+                if not self._waiting:
+                    self._stalled = 0
+                    return False
+                if admitted:
+                    # budget-1 admissions finished instantly: that is progress
+                    self._stalled = 0
+                else:
+                    # zero active slots, zero admissions, a non-empty queue:
+                    # nothing inside the engine can free capacity.  Shed the
+                    # head after `stall_patience` such steps instead of
+                    # spinning forever on externally-held or leaked blocks.
+                    self._stalled += 1
+                    if self._stalled >= self.scfg.stall_patience:
+                        info = self._reqs[self._waiting.pop(0)]
+                        self.stats["shed"] += 1
+                        self._finish(
+                            info,
+                            RequestStatus.REJECTED,
+                            f"shed by watchdog: no admission progress in "
+                            f"{self._stalled} idle steps",
+                        )
+                        self._stalled = 0
+                return bool(self._waiting)
+            self._stalled = 0
 
-        B = self.scfg.batch
-        keys = np.zeros((B, 2), np.uint32)
-        ts = np.zeros((B,), np.int32)
-        for s, st in self._slots.items():
-            keys[s], ts[s] = self._reqs[st.rid].key, st.emitted
-        if self._kv_sums is not None:
-            # the one block each live row legally appends to this step:
-            # decode writes KV at position plen + emitted - 1 (the first
-            # generated token's KV lands on the next step's feed)
-            bs = self.scfg.block_size
+            B = self.scfg.batch
+            keys = np.zeros((B, 2), np.uint32)
+            ts = np.zeros((B,), np.int32)
             for s, st in self._slots.items():
-                row = self._rows[s]
-                self._touched.add(row.blocks[(row.plen + st.emitted - 1) // bs])
-        toks = jnp.asarray(self._cur_tok[:, None])
-        jkeys, jts = jnp.asarray(keys), jnp.asarray(ts)
-        if self._abft:
-            fault = self._fault.copy()
-            from repro.kernels.abft import FAULT_SCRUB
+                keys[s], ts[s] = self._reqs[st.rid].key, st.emitted
+            if self._kv_sums is not None:
+                # the one block each live row legally appends to this step:
+                # decode writes KV at position plen + emitted - 1 (the first
+                # generated token's KV lands on the next step's feed)
+                bs = self.scfg.block_size
+                for s, st in self._slots.items():
+                    row = self._rows[s]
+                    self._touched.add(row.blocks[(row.plen + st.emitted - 1) // bs])
+            toks = jnp.asarray(self._cur_tok[:, None])
+            jkeys, jts = jnp.asarray(keys), jnp.asarray(ts)
+        with _span("serve.decode", rows=len(self._slots)):
+            if self._abft:
+                fault = self._fault.copy()
+                from repro.kernels.abft import FAULT_SCRUB
 
-            fault[FAULT_SCRUB] = self._step_no % self.scfg.kernel.scrub_every == 0
-            (nxt, bad, flags), self.caches = self._decode_call(
-                self.params, toks, self.caches, jkeys, jts,
-                jnp.asarray(fault),
-            )
-            self._fault = np.zeros((8,), np.int32)  # transient: one shot
-            if int(flags):
-                nxt, bad = self._sdc_recover(int(flags), toks, jkeys, jts)
-        else:
-            (nxt, bad), self.caches = self._decode_call(
-                self.params, toks, self.caches, jkeys, jts
-            )
-        nxt = np.asarray(nxt)
-        bad = np.asarray(bad)
-        self._cur_tok = nxt.copy()
-        if self.scfg.guard_nan and bad.any():
-            # quarantine BEFORE emission: a poisoned row's sampled token is
-            # garbage and must reach neither the output nor the journal
-            for s in [s for s in sorted(self._slots) if bad[s]]:
-                self._quarantine(
-                    s, "non-finite logits: KV/activation corruption"
+                fault[FAULT_SCRUB] = self._step_no % self.scfg.kernel.scrub_every == 0
+                (nxt, bad, flags), self.caches = self._decode_call(
+                    self.params, toks, self.caches, jkeys, jts,
+                    jnp.asarray(fault),
                 )
+                self._fault = np.zeros((8,), np.int32)  # transient: one shot
+                if int(flags):
+                    nxt, bad = self._sdc_recover(int(flags), toks, jkeys, jts)
+            else:
+                (nxt, bad), self.caches = self._decode_call(
+                    self.params, toks, self.caches, jkeys, jts
+                )
+            nxt = np.asarray(nxt)
+            bad = np.asarray(bad)
+        with _span("serve.emit") as span:
+            self._cur_tok = nxt.copy()
+            if self.scfg.guard_nan and bad.any():
+                # quarantine BEFORE emission: a poisoned row's sampled token is
+                # garbage and must reach neither the output nor the journal
+                for s in [s for s in sorted(self._slots) if bad[s]]:
+                    self._quarantine(
+                        s, "non-finite logits: KV/activation corruption"
+                    )
 
-        finished = []
-        for s in sorted(self._slots):
-            st = self._slots.get(s)
-            if st is None:
-                continue  # an on_token callback cancelled this row mid-loop
-            tok = int(nxt[s])
-            out = self._outputs[st.rid]
-            if st.emitted < st.replay:
-                # preemption recovery: the decode programs are
-                # deterministic, so the replayed token must re-derive the
-                # recorded one bitwise; it was already emitted pre-eviction
-                assert tok == out[st.emitted], (
-                    f"request {st.rid}: recovery replay diverged at token "
-                    f"{st.emitted} ({tok} != recorded {out[st.emitted]})"
-                )
+            finished = []
+            for s in sorted(self._slots):
+                st = self._slots.get(s)
+                if st is None:
+                    continue  # an on_token callback cancelled this row mid-loop
+                tok = int(nxt[s])
+                out = self._outputs[st.rid]
+                if st.emitted < st.replay:
+                    # preemption recovery: the decode programs are
+                    # deterministic, so the replayed token must re-derive the
+                    # recorded one bitwise; it was already emitted pre-eviction
+                    assert tok == out[st.emitted], (
+                        f"request {st.rid}: recovery replay diverged at token "
+                        f"{st.emitted} ({tok} != recorded {out[st.emitted]})"
+                    )
+                    st.emitted += 1
+                    if st.emitted >= st.budget:
+                        # crash recovery can replay a request to COMPLETION
+                        # (it finished after the last snapshot): the journaled
+                        # final token re-derives here and no fresh emission
+                        # remains to trigger the ordinary finish path below
+                        finished.append((s, st.rid))
+                    continue
+                out.append(tok)
                 st.emitted += 1
-                if st.emitted >= st.budget:
-                    # crash recovery can replay a request to COMPLETION
-                    # (it finished after the last snapshot): the journaled
-                    # final token re-derives here and no fresh emission
-                    # remains to trigger the ordinary finish path below
+                done = st.emitted >= st.budget
+                self._emit_cbs(self._reqs[st.rid], tok, st.emitted - 1, done, on_token)
+                if done:
                     finished.append((s, st.rid))
-                continue
-            out.append(tok)
-            st.emitted += 1
-            done = st.emitted >= st.budget
-            self._emit_cbs(self._reqs[st.rid], tok, st.emitted - 1, done, on_token)
-            if done:
-                finished.append((s, st.rid))
-        for s, rid in finished:
-            st = self._slots.get(s)
-            if st is None or st.rid != rid:
-                continue  # the done-callback already cancelled it
-            self._release_slot(s)  # backfilled at the next step
-            self._finish(self._reqs[rid], RequestStatus.FINISHED, "")
-        if self._kv_sums is not None and not self._abft:
-            self._audit_kv_checksums()
+            for s, rid in finished:
+                st = self._slots.get(s)
+                if st is None or st.rid != rid:
+                    continue  # the done-callback already cancelled it
+                self._release_slot(s)  # backfilled at the next step
+                self._finish(self._reqs[rid], RequestStatus.FINISHED, "")
+            if self._kv_sums is not None and not self._abft:
+                self._audit_kv_checksums()
+            span.set_metadata(finished=len(finished))
         return True
 
     def pop_result(self, rid: int) -> RequestResult:

@@ -72,6 +72,16 @@ def _compiled_text(fn, *shapes) -> str:
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
+def _kernel_names(text: str) -> list[str]:
+    """Instruction names of the Mosaic kernels in a compiled program: the
+    text a profiler trace names each kernel's operation by."""
+    return [
+        line.split(" = ", 1)[0].strip().lstrip("%").rsplit(".", 1)[0]
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    ]
+
+
 @pytest.mark.parametrize("bk", [None, BLOCK], ids=["auto_bk", "bk16"])
 def test_contiguous_decode_compiles(spec, bk):
     from repro.kernels.flash_attention.ops import decode_attention
@@ -85,7 +95,7 @@ def test_contiguous_decode_compiles(spec, bk):
         spec((SLOTS, MAX_LEN, KV, D)),
         spec((SLOTS,), jnp.int32),
     )
-    assert "tpu_custom_call" in text
+    assert _kernel_names(text) == ["decode_attention"]
 
 
 def test_paged_decode_compiles(spec):
@@ -103,7 +113,40 @@ def test_paged_decode_compiles(spec):
         spec((SLOTS, n_blk), jnp.int32),
         spec((SLOTS,), jnp.int32),
     )
-    assert "tpu_custom_call" in text
+    assert _kernel_names(text) == ["decode_attention_paged"]
+
+
+def test_engine_decode_program_names_its_kernel(one_chip, monkeypatch):
+    """The serve engine's whole paged decode program (``jit_decode_fn``), as
+    the chip runs it: the paged kernel keeps its name through inlining, so
+    a trace can find it by name rather than as the program's only kernel."""
+    from repro.arch.model_zoo import build
+    from repro.configs.registry import get
+    from repro.serve.engine import Engine, KVConfig, SchedulerConfig, ServeConfig
+
+    cfg = get("smollm-360m-smoke")
+    params = jax.eval_shape(build(cfg).init, jax.random.PRNGKey(0))
+    eng = Engine(cfg, params, ServeConfig(
+        max_len=256,
+        scheduler=SchedulerConfig(batch=SLOTS),
+        kv=KVConfig(layout="paged", block_size=BLOCK),
+    ))
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+        )
+
+    i32 = jnp.zeros((SLOTS,), jnp.int32)
+    # the engine picks the Pallas kernel when JAX's backend is a TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lowered = eng._decode.lower(
+        on_chip(params), on_chip(i32[:, None]), on_chip(eng.caches),
+        on_chip(jnp.zeros((SLOTS, 2), jnp.uint32)), on_chip(i32),
+    )
+    monkeypatch.undo()
+    text = lowered.compile().as_text()
+    assert _kernel_names(text) == ["decode_attention_paged"]
 
 
 def test_prefill_flash_attention_compiles(spec):
