@@ -243,39 +243,27 @@ def multihead_attention(
     decode_lengths = None
     attn_impl = _ATTENTION_IMPL[0]
     if cache is not None and "kpool" in cache:
-        # paged slot cache: this token's K/V scatter through the block
-        # table into the shared pool at (table[row, pos // bs], pos % bs).
-        # The engine guarantees exclusive write ownership of that block
-        # (fresh allocation or copy-on-write before the step), so rows
-        # never collide; evicted rows aim every table entry at the sink
-        # block, whose garbage nothing live reads.
+        # paged slot cache: the layer loop carries the stacked, packed pools
+        # (serve/kvcache.py) and names this layer ``cache["layer"]``; this
+        # token's K/V go into them in place, through the block table
         if Tq != 1 or kv_x is not None:
             raise ValueError(
                 "paged KV caches serve single-token decode only; admission "
                 "prefills into a contiguous scratch and packs blocks"
             )
-        bs = cache["kpool"].shape[1]
-        p_ins = cache["len"]                        # (B,) write positions
-        phys = jnp.take_along_axis(
-            cache["table"], (p_ins // bs)[:, None], axis=1
-        )[:, 0]
-        kpool = cache["kpool"].at[phys, p_ins % bs].set(
-            k[:, 0].astype(cache["kpool"].dtype)
-        )
-        vpool = cache["vpool"].at[phys, p_ins % bs].set(
-            v[:, 0].astype(cache["vpool"].dtype)
-        )
-        new_cache = {
-            "kpool": kpool, "vpool": vpool,
-            "table": cache["table"], "len": p_ins + 1,
-        }
+        from repro.serve.kvcache import paged_write_token
+
+        li, p_ins = cache["layer"], cache["len"]    # p_ins: (B,) write positions
+        kpool = paged_write_token(cache["kpool"], li, cache["table"], p_ins, k[:, 0])
+        vpool = paged_write_token(cache["vpool"], li, cache["table"], p_ins, v[:, 0])
+        new_cache = {**cache, "kpool": kpool, "vpool": vpool, "len": p_ins + 1}
         from repro.kernels.flash_attention.ops import decode_attention_paged
 
         g = h // kv
         qg = q.reshape(B, kv, g, hd)
         ctx = decode_attention_paged(
             qg,
-            kpool, vpool, cache["table"], p_ins + 1,
+            kpool, vpool, cache["table"], p_ins + 1, li,
             # supports_paged admits only all-global configs, so the scanned
             # per-layer window (traced here) is always the 2^30 sentinel
             window=None,
@@ -286,7 +274,7 @@ def multihead_attention(
         trace = _ABFT[0]
         if trace is not None:
             ctx = trace.check_paged_attention(
-                ctx, qg, kpool, vpool, cache["table"], p_ins + 1
+                ctx, qg, kpool, vpool, cache["table"], p_ins + 1, li
             )
         return _mm(ctx.reshape(B, Tq, h * hd), params["wo"]), new_cache
     if cache is not None:

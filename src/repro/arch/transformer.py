@@ -200,6 +200,8 @@ class Model:
             # scan so ring buffers stay window-sized (a 1k-window layer must
             # not allocate 500k slots).
             return self._backbone_local_global(params, x, positions, caches)
+        if caches is not None and "kpool" in caches:
+            return self._backbone_paged(params, x, positions, caches)
 
         if cfg.mixer == "rwkv6":
             def body(x, p_c):
@@ -213,13 +215,8 @@ class Model:
             # scanned layers, and passed down as a hint
             ragged = None
             if caches is not None:
-                if "kpool" in caches:
-                    # paged caches exist only for all-global configs
-                    # (kvcache.supports_paged), so the invariant is free
-                    ragged = True
-                else:
-                    size = caches["k"].shape[2]
-                    ragged = bool((layer_windows(cfg) >= size).all())
+                size = caches["k"].shape[2]
+                ragged = bool((layer_windows(cfg) >= size).all())
 
             def body(x, p_c_w_i):
                 p, c, w, li = p_c_w_i
@@ -255,6 +252,54 @@ class Model:
             if trace is not None:
                 trace.layer = None
                 trace.flags.append(jnp.any(flags))
+        return x, new_caches, jnp.sum(auxs)
+
+    def _backbone_paged(self, params, x, positions, caches):
+        """Decode over the paged pools (serve/kvcache.py).  The stacked K/V
+        pools are carried through the layer scan, not scanned: a scanned
+        pool would be sliced out and restacked every layer, a pool-sized
+        copy each way.  Carried, each layer writes its token into the pools
+        in place and the kernel reads layer ``li`` of them by index.  The
+        block table and lengths are the same for every layer."""
+        cfg = self.cfg
+        table, length = caches["table"][0], caches["len"][0]
+
+        def body(carry, p_w_i):
+            x, kpool, vpool = carry
+            p, w, li = p_w_i
+            trace = L.abft_active()
+            if trace is not None:
+                trace.layer = li
+            cache = {
+                "kpool": kpool, "vpool": vpool, "table": table,
+                "len": length, "layer": li,
+            }
+            # paged caches exist only for all-global configs
+            # (kvcache.supports_paged), so the ragged invariant is free
+            y, nc, aux = attn_block_apply(
+                p, cfg, x, window=w, positions=positions, cache=cache,
+                ragged_ok=True,
+            )
+            flag = (
+                trace.drain() if trace is not None
+                else jnp.zeros((), jnp.bool_)
+            )
+            return (y, nc["kpool"], nc["vpool"]), (aux, flag)
+
+        xs = (
+            params["layers"], jnp.asarray(layer_windows(cfg)),
+            jnp.arange(cfg.n_layers),
+        )
+        (x, kpool, vpool), (auxs, flags) = jax.lax.scan(
+            body, (x, caches["kpool"], caches["vpool"]), xs
+        )
+        trace = L.abft_active()
+        if trace is not None:
+            trace.layer = None
+            trace.flags.append(jnp.any(flags))
+        new_caches = {
+            **caches, "kpool": kpool, "vpool": vpool, "len": caches["len"] + 1,
+        }
         return x, new_caches, jnp.sum(auxs)
 
     def _split_groups(self, params):

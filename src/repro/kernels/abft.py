@@ -36,8 +36,9 @@ element — and of the checksum row — to a low-precision output dtype
 For decode attention there is no checksum identity (softmax is
 nonlinear), so the check is a sampled *output fingerprint*: recompute k
 rows of the paged online-softmax on the XLA twin — which is bitwise
-equal to the served kernel on equal inputs (the repo's differential
-oracle rests on this) — and compare.
+equal to the served twin on equal inputs (the repo's differential
+oracle rests on this; the Pallas kernel sums a packed block's tokens in
+another order) — and compare.
 
 :class:`AbftTrace` is the trace-scoped recorder the engine installs via
 ``layers.abft_override``.  It also owns the *fault operand*: an int32
@@ -286,11 +287,13 @@ class AbftTrace:
         return out2.reshape(x.shape[:-1] + (w.shape[-1],))
 
     # --------------------------------------------------------- attention --
-    def check_paged_attention(self, ctx, q, kpool, vpool, tables, lengths):
+    def check_paged_attention(self, ctx, q, kpool, vpool, tables, lengths, layer):
         """Fingerprint-check one paged decode-attention output ``ctx``
-        (shape (B, KV, G, d)) by recomputing ``k`` sampled rows on the XLA
-        twin, which is bitwise-equal to the served kernel on equal logical
-        contents.  Returns ``ctx`` with any injection applied."""
+        (shape (B, KV, G, d)) of layer ``layer`` of the stacked pools by
+        recomputing ``k`` sampled rows on the XLA twin, which computes the
+        served kernel's recurrence on equal logical contents (bitwise on
+        CPU; the kernel may sum a packed block's tokens in another order).
+        Returns ``ctx`` with any injection applied."""
         from repro.kernels.flash_attention.ops import decode_attention_paged
 
         idx = self.attn_calls
@@ -301,7 +304,8 @@ class AbftTrace:
         ctx = c2.reshape(ctx.shape)
         rows = jnp.asarray(sample_rows(B, self.mode))
         ref = decode_attention_paged(
-            q[rows], kpool, vpool, tables[rows], lengths[rows], impl="xla"
+            q[rows], kpool, vpool, tables[rows], lengths[rows], layer,
+            impl="xla",
         ).astype(jnp.float32)
         got = ctx[rows].astype(jnp.float32)
         scale = jnp.max(jnp.abs(ref))

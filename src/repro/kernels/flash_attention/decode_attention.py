@@ -46,27 +46,35 @@ the correction factor is ``exp(0)``), so padding rows to the batch max is
 bitwise-neutral, which keeps batched serving bitwise-equal to solo runs.
 
 Paged variants (:func:`flash_decode_paged_pallas`,
-:func:`decode_attention_paged_xla`): k/v live in a shared **block pool**
-``(num_blocks, block_size, KV, d)`` instead of a dense per-slot axis, and
-each row carries a **block table** ``(B, max_blocks)`` mapping its logical
-block ``j`` to a physical pool block.  The grid stays
-``(batch, kv_splits)`` with ``kv_splits == max_blocks``; the
-only change is that the k/v index maps go through the table — a second
-scalar-prefetch operand — so block-table *contents* never recompile, and
-dead splits alias to the row's last live **physical** block exactly like
-the dense variant.  Because the KV split boundary is the block boundary,
-the paged recurrence visits the same logical key ranges in the same order
-as the dense kernel at ``bk == block_size``: outputs are bitwise equal,
-which is what lets the contiguous serve engine act as the paged engine's
-differential oracle.  An optional ``window`` additionally masks
-``k_idx <= length - 1 - window`` for sliding-window rows (position ==
-logical index in the paged layout; there is no ring).
+:func:`decode_attention_paged_xla`): k/v live in the serve engine's
+stacked **block pools** ``(L, num_blocks, bs // r, r * KV * d)`` — every
+layer's pool, packed ``r`` tokens to a row so a row is whole 128-lane tiles
+(``serve.kvcache.pack_blocks``) — and each row carries a **block table**
+``(B, max_blocks)`` mapping its logical block ``j`` to a physical pool
+block.  The grid stays ``(batch, kv_splits)`` with ``kv_splits ==
+max_blocks``; the k/v index maps go through the table and a layer index —
+scalar-prefetch operands, so table contents and the layer never recompile
+— and dead splits alias to the row's last live **physical** block exactly
+like the dense variant.  Reading a layer by index, the kernel takes the
+decode program's pools as they are, with no per-layer slice.  The body
+takes each head's ``r`` lane slices of a packed block (one per token
+parity) through the same online-softmax update.  Because the KV split
+boundary is the block boundary, the paged recurrence visits the same
+logical key ranges in the same order as the dense kernel at ``bk ==
+block_size``; within a block the kernel sums the ``r`` token parities
+separately (so it matches the dense kernel to rounding, exactly at ``r ==
+1``), while the XLA twin unpacks the block and is bitwise equal to the
+dense twin, which is what lets the contiguous serve engine act as the
+paged engine's differential oracle on CPU.  An optional ``window``
+additionally masks ``k_idx <= length - 1 - window`` for sliding-window
+rows (position == logical index in the paged layout; there is no ring).
 """
 
 from __future__ import annotations
 
 import functools
 import math
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -95,16 +103,18 @@ def _live_keys(k_idx, length, window):
 def _decode_step(
     length,                       # live keys of this row (SMEM scalar)
     q_ref,                        # (1, KV, G, d)
-    k_ref,                        # (1, bk, KV, d)
-    v_ref,                        # (1, bk, KV, d)
+    parts,                        # h -> [(k (n, d), v (n, d), stride, first)]
     o_ref,                        # (1, KV, G, d)
     m_ref, l_ref, acc_ref,        # VMEM scratch: (KV, G), (KV, G), (KV, G, d) fp32
     *, bk: int, n_k: int, scale: float, window: int | None,
 ):
-    """One KV split of one row, shared by the dense and paged kernels.  The
-    K/V block carries every kv head (its last two dims are the array's
-    ``(KV, d)``, which is what the TPU block-shape rule accepts for any KV);
-    a static loop over heads runs the online-softmax update per head."""
+    """One KV split of one row, shared by the dense and paged kernels.  A
+    static loop over kv heads runs the online-softmax update per head over
+    the head's K/V slices ``parts(h)``: part ``(k, v, stride, first)`` holds
+    the block's keys ``first, first + stride, ...``.  The dense kernel has
+    one part per head (stride 1); the paged kernel one per token of a packed
+    pool row.  The running max covers every part before any is exponentiated,
+    so the update is the one-part update with its sums split by part."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -115,23 +125,37 @@ def _decode_step(
     def _live():
         for h in range(q_ref.shape[1]):     # static: one pass per kv head
             q = q_ref[0, h]                   # (G, d)
-            k = k_ref[0, :, h, :]             # (bk, d)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale                         # (G, bk)
-            k_idx = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(_live_keys(k_idx, length, window), s, NEG_INF)
+            ss, vs = [], []
+            for k, v, stride, first in parts(h):
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) * scale                     # (G, n)
+                k_idx = j * bk + first + stride * jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 1
+                )
+                ss.append(jnp.where(_live_keys(k_idx, length, window), s, NEG_INF))
+                vs.append(v)
 
             m_prev = m_ref[h]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-            p = jnp.exp(s - m_new[:, None])
+            m_new = functools.reduce(
+                jnp.maximum, [jnp.max(s, axis=1) for s in ss], m_prev
+            )
+            ps = [jnp.exp(s - m_new[:, None]) for s in ss]
             corr = jnp.exp(m_prev - m_new)
-            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1)
+            l_ref[h] = l_ref[h] * corr + functools.reduce(
+                operator.add, [jnp.sum(p, axis=1) for p in ps]
+            )
             m_ref[h] = m_new
-            acc_ref[h] = acc_ref[h] * corr[:, None] + jax.lax.dot_general(
-                p.astype(v_ref.dtype), v_ref[0, :, h, :],
-                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            acc_ref[h] = acc_ref[h] * corr[:, None] + functools.reduce(
+                operator.add,
+                [
+                    jax.lax.dot_general(
+                        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )
+                    for p, v in zip(ps, vs)
+                ],
             )
 
     @pl.when(j == n_k - 1)
@@ -147,9 +171,12 @@ def _decode_scratch(KV: int, G: int, d: int) -> list:
     ]
 
 
-def _decode_kernel(lens_ref, *refs, **kw):
-    # lens_ref: SMEM (B,) int32 scalar-prefetch
-    _decode_step(lens_ref[pl.program_id(0)], *refs, window=None, **kw)
+def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, *refs, **kw):
+    # lens_ref: SMEM (B,) int32 scalar-prefetch; k/v blocks (1, bk, KV, d)
+    def parts(h):
+        return [(k_ref[0, :, h, :], v_ref[0, :, h, :], 1, 0)]
+
+    _decode_step(lens_ref[pl.program_id(0)], q_ref, parts, *refs, window=None, **kw)
 
 
 def flash_decode_pallas(
@@ -248,50 +275,70 @@ def decode_attention_xla(
 # ------------------------------------------------------------ paged variants
 
 
-def _paged_decode_kernel(lens_ref, table_ref, *refs, **kw):
-    # lens_ref: SMEM (B,) int32; table_ref: SMEM (B, n_blk) int32 — both
-    # scalar-prefetch; the table is only read by the index maps
-    _decode_step(lens_ref[pl.program_id(0)], *refs, **kw)
+def _paged_decode_kernel(lens_ref, table_ref, layer_ref, q_ref, k_ref, v_ref,
+                         *refs, r: int, **kw):
+    # scalar prefetch (SMEM): lens (B,), table (B, n_blk), layer (1,) int32;
+    # the table and layer are only read by the index maps.  k/v blocks are
+    # one packed pool block (1, bs // r, r * KV * d): token t of the block,
+    # head h sits at row t // r, lanes (t % r) * KV * d + h * d.
+    KV, d = q_ref.shape[1], q_ref.shape[3]
+
+    def parts(h):
+        return [
+            (
+                k_ref[0, :, pl.ds(t * KV * d + h * d, d)],
+                v_ref[0, :, pl.ds(t * KV * d + h * d, d)],
+                r,
+                t,
+            )
+            for t in range(r)
+        ]
+
+    _decode_step(lens_ref[pl.program_id(0)], q_ref, parts, *refs, **kw)
 
 
 def flash_decode_paged_pallas(
     q: jax.Array,         # (B, KV, G, d) one query token per (row, head)
-    kpool: jax.Array,     # (num_blocks, bs, KV, d) shared block pool
-    vpool: jax.Array,     # (num_blocks, bs, KV, d)
+    kpool: jax.Array,     # (L, num_blocks, bs // r, r * KV * d) packed pools
+    vpool: jax.Array,     # (L, num_blocks, bs // r, r * KV * d)
     tables: jax.Array,    # (B, n_blk) int32 logical -> physical block
     lengths: jax.Array,   # (B,) int32 live tokens per row (traced)
+    layer: jax.Array,     # () int32 which layer's pool to read (traced)
     *,
     window: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     B, KV, G, d = q.shape
-    bs = kpool.shape[1]
+    rows, lanes = kpool.shape[2:]
+    r = lanes // (KV * d)
+    bs = rows * r
     n_blk = tables.shape[1]
     scale = 1.0 / math.sqrt(d)
     lengths = jnp.clip(lengths.astype(jnp.int32), 1, n_blk * bs)
     tables = tables.astype(jnp.int32)
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
 
-    def kv_block(b, j, lens, tabs):
+    def kv_block(b, j, lens, tabs, li):
         last = last_live_block(lens[b], bs)
-        return (tabs[b, jnp.minimum(j, last)], 0, 0, 0)
+        return (li[0], tabs[b, jnp.minimum(j, last)], 0, 0)
 
-    def row(b, j, lens, tabs):
+    def row(b, j, lens, tabs, li):
         return (b, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, n_blk),
         in_specs=[
             pl.BlockSpec((1, KV, G, d), row),
-            pl.BlockSpec((1, bs, KV, d), kv_block),
-            pl.BlockSpec((1, bs, KV, d), kv_block),
+            pl.BlockSpec((None, 1, rows, lanes), kv_block),
+            pl.BlockSpec((None, 1, rows, lanes), kv_block),
         ],
         out_specs=pl.BlockSpec((1, KV, G, d), row),
         scratch_shapes=_decode_scratch(KV, G, d),
     )
     kern = functools.partial(
         _paged_decode_kernel,
-        bk=bs, n_k=n_blk, scale=scale, window=window,
+        r=r, bk=bs, n_k=n_blk, scale=scale, window=window,
     )
     return pl.pallas_call(
         kern,
@@ -299,36 +346,40 @@ def flash_decode_paged_pallas(
         out_shape=jax.ShapeDtypeStruct((B, KV, G, d), q.dtype),
         interpret=interpret,
         name="decode_attention_paged",
-    )(lengths, tables, q, kpool, vpool)
+    )(lengths, tables, layer, q, kpool, vpool)
 
 
 def decode_attention_paged_xla(
     q: jax.Array,         # (B, KV, G, d)
-    kpool: jax.Array,     # (num_blocks, bs, KV, d)
-    vpool: jax.Array,     # (num_blocks, bs, KV, d)
+    kpool: jax.Array,     # (L, num_blocks, bs // r, r * KV * d)
+    vpool: jax.Array,     # (L, num_blocks, bs // r, r * KV * d)
     tables: jax.Array,    # (B, n_blk) int32
     lengths: jax.Array,   # (B,) int32
+    layer: jax.Array,     # () int32
     *,
     window: int | None = None,
 ) -> jax.Array:
     """Gather-based jnp twin of the paged kernel: the same blocked
     recurrence as :func:`decode_attention_xla` with the KV block fetched
-    through the block table (one ``(B,)`` gather per live split) instead of
-    a dynamic slice.  At ``bk == block_size`` the two twins are bitwise
-    equal on equal logical contents — the paged serve engine's differential
-    oracle rests on this."""
+    through the block table (one ``(B,)`` gather of layer ``layer`` per live
+    split, unpacked back to ``(B, bs, KV, d)``) instead of a dynamic slice.
+    At ``bk == block_size`` the two twins are bitwise equal on equal logical
+    contents — the paged serve engine's differential oracle rests on
+    this."""
     B, KV, G, d = q.shape
-    bs = kpool.shape[1]
+    rows, lanes = kpool.shape[2:]
+    bs = rows * (lanes // (KV * d))
     scale = 1.0 / math.sqrt(d)
     lengths = jnp.clip(lengths.astype(jnp.int32), 1, tables.shape[1] * bs)
     tables = tables.astype(jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32)
     n_live = jnp.max((lengths + bs - 1) // bs)
 
     def body(state):
         j, m, l, acc = state
         phys = jax.lax.dynamic_slice_in_dim(tables, j, 1, axis=1)[:, 0]
-        kb = jnp.take(kpool, phys, axis=0)              # (B, bs, KV, d)
-        vb = jnp.take(vpool, phys, axis=0)
+        kb = kpool[layer, phys].reshape(B, bs, KV, d)
+        vb = vpool[layer, phys].reshape(B, bs, KV, d)
         s = jnp.einsum(
             "bhgd,bshd->bhgs", q, kb, preferred_element_type=jnp.float32
         ) * scale                                       # (B, KV, G, bs)

@@ -207,34 +207,37 @@ def _run_decode_kernel(q, kern, interp: bool):
 
 def decode_attention_paged(
     q: jax.Array,         # (B, KV, G, d) one query token per row
-    kpool: jax.Array,     # (num_blocks, bs, KV, d) shared block pool
-    vpool: jax.Array,     # (num_blocks, bs, KV, d)
+    kpool: jax.Array,     # (L, num_blocks, bs // r, r * KV * d) packed pools
+    vpool: jax.Array,     # (L, num_blocks, bs // r, r * KV * d)
     tables: jax.Array,    # (B, n_blk) int32 logical -> physical block
     lengths: jax.Array,   # (B,) int32 live tokens per row (traced)
+    layer: jax.Array,     # () int32 which layer's pool to read (traced)
     *,
     window: int | None = None,
     impl: str | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Block-table-indirect ragged flash-decoding; returns (B, KV, G, d).
+    """Block-table-indirect ragged flash-decoding over layer ``layer`` of
+    the stacked, packed pools (``serve.kvcache.pack_blocks``); returns
+    (B, KV, G, d).
 
     The KV split size is the pool's block size (splits are physical
     blocks), so there is no ``bk`` knob: the logical reduction order is
-    fixed by the paged layout, which is what makes this path bitwise
+    fixed by the paged layout, which is what makes the XLA twin bitwise
     comparable to the contiguous twin at ``bk == block_size``.  ``impl``
     dispatches exactly like :func:`decode_attention`."""
-    B, KV, G, d = q.shape
     if impl is None:
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     if impl == "xla":
         return decode_attention_paged_xla(
-            q, kpool, vpool, tables, lengths, window=window
+            q, kpool, vpool, tables, lengths, layer, window=window
         )
     interp = _should_interpret() if interpret is None else interpret
     return _run_decode_kernel(
         q,
         lambda qp: flash_decode_paged_pallas(
-            qp, kpool, vpool, tables, lengths, window=window, interpret=interp
+            qp, kpool, vpool, tables, lengths, layer,
+            window=window, interpret=interp,
         ),
         interp,
     )

@@ -56,24 +56,24 @@ def decode_attention_ref(
 
 def decode_attention_paged_ref(
     q: jax.Array,        # (B, KV, G, d)
-    kpool: jax.Array,    # (num_blocks, bs, KV, d)
-    vpool: jax.Array,    # (num_blocks, bs, KV, d)
+    kpool: jax.Array,    # (L, num_blocks, bs // r, r * KV * d) packed pools
+    vpool: jax.Array,    # (L, num_blocks, bs // r, r * KV * d)
     tables: jax.Array,   # (B, n_blk) int32
     lengths: jax.Array,  # (B,) int32
+    layer: int | jax.Array,
     *,
     window: int | None = None,
 ) -> jax.Array:
     """Dense oracle for paged decode: materialize each row's logical KV
-    sequence by gathering its block-table chain out of the pool, then run
-    the dense masked softmax (optionally sliding-window; logical index ==
-    absolute position in the paged layout)."""
-    B = q.shape[0]
-    bs = kpool.shape[1]
-    n_blk = tables.shape[1]
-    # (B, n_blk, bs, KV, d) -> (B, S, KV, d) dense per-row sequences
-    k = jnp.take(kpool, tables, axis=0).reshape(B, n_blk * bs, *kpool.shape[2:])
-    v = jnp.take(vpool, tables, axis=0).reshape(B, n_blk * bs, *vpool.shape[2:])
-    d = q.shape[-1]
+    sequence by gathering its block-table chain out of layer ``layer`` of
+    the packed pools and unpacking it (a packed row holds ``r`` consecutive
+    tokens, head-major within a token), then run the dense masked softmax
+    (optionally sliding-window; logical index == absolute position in the
+    paged layout)."""
+    B, KV, _, d = q.shape
+    # (B, n_blk, bs // r, r * KV * d) -> (B, S, KV, d) dense per-row sequences
+    k = jnp.take(kpool[layer], tables, axis=0).reshape(B, -1, KV, d)
+    v = jnp.take(vpool[layer], tables, axis=0).reshape(B, -1, KV, d)
     s = jnp.einsum("bhgd,bshd->bhgs", q, k).astype(jnp.float32) / math.sqrt(d)
     k_idx = jnp.arange(k.shape[1])[None, :]
     live = k_idx < lengths[:, None]                             # (B, S)

@@ -1115,13 +1115,15 @@ class Engine:
         if scfg.kv_checksum or (self._abft and self._paged):
 
             def pool_sums_fn(caches):
+                # per block (axis 1 of the packed pools): every layer,
+                # row and lane
                 k = jnp.sum(
                     jnp.abs(caches["kpool"].astype(jnp.float32)),
-                    axis=(0, 2, 3, 4),
+                    axis=(0, 2, 3),
                 )
                 v = jnp.sum(
                     jnp.abs(caches["vpool"].astype(jnp.float32)),
-                    axis=(0, 2, 3, 4),
+                    axis=(0, 2, 3),
                 )
                 return k + v
 

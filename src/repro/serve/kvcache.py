@@ -42,6 +42,7 @@ these writes maintain — live entries of every cache occupy exactly slots
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
@@ -202,17 +203,26 @@ def mask_prompt_tail(caches: Any, true_len: jax.Array) -> Any:
 # over-provisioning, at serve granularity).  The paged layout carves the
 # same HBM into fixed-size blocks:
 #
-#   kpool/vpool: (num_blocks, block_size, kv_heads, head_dim) per layer
+#   kpool/vpool: (layers, num_blocks, block_size // r, r * kv_heads * head_dim)
 #   table:       (batch, max_len // block_size) int32, logical -> physical
 #   len:         (batch,) int32 live tokens per row
+#
+# A pool row packs ``r`` consecutive tokens of a block (``tokens_per_row``):
+# token t, head h, dim e lives at row t // r, lane (t % r) * kv_heads *
+# head_dim + h * head_dim + e — the row-major memory of (block_size,
+# kv_heads, head_dim), so ``pack_blocks``/``unpack_blocks`` are pure
+# reshapes.  ``r`` makes a row whole 128-lane tiles, so the TPU stores the
+# pool unpadded in the layout the decode kernel reads (a (5, 64) minor pair
+# would pad 3.2x under the (8, 128) tiling).
 #
 # Physical block 0 is the SINK: never allocated, evicted rows point every
 # table entry at it so the always-full-batch decode program's garbage
 # writes land somewhere harmless.  Host-side ownership (refcounts, the
 # free list, the prefix index) lives in :class:`BlockPool`; the device
 # pytree is mutated only through the donated pure ops below
-# (`paged_store_row_blocks` / `paged_set_row` / `paged_copy_block`), so the
-# decode loop keeps the PR-4 zero-copy donation contract.
+# (`paged_store_row_blocks` / `paged_set_row` / `paged_copy_block`, and
+# `paged_write_token` inside the decode program), so the decode loop keeps
+# the zero-copy donation contract.
 #
 # Prefix sharing: the pool keys each block by ``(previous physical block,
 # tokens written in it)`` — a radix chain, vLLM-style.  Requests whose
@@ -239,6 +249,41 @@ def supports_paged(cfg: ModelConfig) -> bool:
     )
 
 
+def tokens_per_row(kv_heads: int, head_dim: int, block_size: int) -> int:
+    """Tokens a packed pool row holds: the fewest whose K (or V) fill whole
+    128-lane tiles, ``128 // gcd(kv_heads * head_dim, 128)``, cut to a
+    divisor of ``block_size``.  (5, 64) packs 2 tokens into 640 lanes;
+    (8, 128) needs no packing."""
+    r = 128 // math.gcd(kv_heads * head_dim, 128)
+    return math.gcd(r, block_size)
+
+
+def pack_blocks(blocks: Any, r: int) -> Any:
+    """``(..., block_size, kv_heads, head_dim)`` -> packed pool rows
+    ``(..., block_size // r, r * kv_heads * head_dim)`` (a reshape)."""
+    *lead, bs, kv, hd = blocks.shape
+    return blocks.reshape(*lead, bs // r, r * kv * hd)
+
+
+def unpack_blocks(rows: Any, kv_heads: int, head_dim: int) -> Any:
+    """Inverse of :func:`pack_blocks`: packed rows ``(..., n, r * kv_heads *
+    head_dim)`` -> tokens ``(..., n * r, kv_heads, head_dim)``."""
+    *lead, n, lanes = rows.shape
+    r = lanes // (kv_heads * head_dim)
+    return rows.reshape(*lead, n * r, kv_heads, head_dim)
+
+
+def token_slot(
+    pos: int, kv_heads: int, head_dim: int, block_size: int
+) -> tuple[int, slice]:
+    """Row and lanes of a packed pool block that hold token ``pos`` of the
+    block (every head): ``pool[layer, block, row, lanes]`` is its K or V,
+    shaped ``(kv_heads * head_dim,)``."""
+    r = tokens_per_row(kv_heads, head_dim, block_size)
+    w = kv_heads * head_dim
+    return pos // r, slice((pos % r) * w, (pos % r + 1) * w)
+
+
 def build_paged_caches(
     cfg: ModelConfig,
     batch: int,
@@ -246,9 +291,9 @@ def build_paged_caches(
     num_blocks: int,
     block_size: int,
 ) -> Any:
-    """Paged decode caches: per-layer block pools stacked over layers, plus
-    per-row block tables / lengths (replicated per layer so the layer scan
-    slices one uniform pytree; the int32 metadata is negligible)."""
+    """Paged decode caches: per-layer packed block pools stacked over
+    layers, plus per-row block tables / lengths (replicated per layer, as
+    every cache leaf has a layer axis; the int32 metadata is negligible)."""
     if not supports_paged(cfg):
         raise ValueError(f"paged KV layout unsupported for {cfg.name}")
     if max_len % block_size:
@@ -259,10 +304,12 @@ def build_paged_caches(
     sd = jnp.dtype(cfg.dtype)
     n_blk = max_len // block_size
     L = cfg.n_layers
+    r = tokens_per_row(kv, hd, block_size)
+    pool = (L, num_blocks, block_size // r, r * kv * hd)
 
     return {
-        "kpool": jnp.zeros((L, num_blocks, block_size, kv, hd), sd),
-        "vpool": jnp.zeros((L, num_blocks, block_size, kv, hd), sd),
+        "kpool": jnp.zeros(pool, sd),
+        "vpool": jnp.zeros(pool, sd),
         "table": jnp.zeros((L, batch, n_blk), jnp.int32),
         "len": jnp.zeros((L, batch), jnp.int32),
     }
@@ -282,9 +329,10 @@ def paged_store_row_blocks(
     admission per distinct block count.  ``caches`` is donated by the
     engine's jit of this function."""
     n_pack = phys.shape[0]
-    bs = caches["kpool"].shape[2]
-    L = caches["kpool"].shape[0]
-    kv, hd = caches["kpool"].shape[3:]
+    L = scratch["k"].shape[0]
+    kv, hd = scratch["k"].shape[3:]
+    r = caches["kpool"].shape[3] // (kv * hd)
+    bs = caches["kpool"].shape[2] * r
     row = jnp.asarray(row, jnp.int32)
     start = jnp.asarray(start_lb, jnp.int32) * bs
 
@@ -294,8 +342,8 @@ def paged_store_row_blocks(
             (jnp.int32(0), row, start, jnp.int32(0), jnp.int32(0)),
             (L, 1, n_pack * bs, kv, hd),
         )
-        blocks = blk[:, 0].reshape(L, n_pack, bs, kv, hd)
-        # (L, n_pack, bs, kv, hd) scattered at pool[:, phys]
+        blocks = pack_blocks(blk[:, 0].reshape(L, n_pack, bs, kv, hd), r)
+        # (L, n_pack, bs // r, r * kv * hd) scattered at pool[:, phys]
         return pool.at[:, phys.astype(jnp.int32)].set(blocks.astype(pool.dtype))
 
     return {
@@ -304,6 +352,37 @@ def paged_store_row_blocks(
         "table": caches["table"],
         "len": caches["len"],
     }
+
+
+def paged_write_token(
+    pool: jax.Array,
+    layer: jax.Array,
+    table: jax.Array,
+    pos: jax.Array,
+    new: jax.Array,
+) -> jax.Array:
+    """Write one decode token's K (or V) of every row into layer ``layer``
+    of a packed pool: row ``b``'s token ``new[b]`` (kv_heads, head_dim) goes
+    to position ``pos[b]``, in block ``table[b, pos[b] // block_size]``.
+    The packed rows holding the tokens are gathered, each token's lanes
+    replaced, and the rows scattered back: one row scatter, in place in
+    the donated (or loop-carried) pool.  The engine guarantees exclusive
+    write ownership of a live row's block (fresh allocation or
+    copy-on-write before the step), so live rows never collide; evicted
+    rows aim every table entry at the sink block, whose garbage nothing
+    live reads."""
+    B, kv, hd = new.shape
+    rows, lanes = pool.shape[2:]
+    r = lanes // (kv * hd)
+    bs = rows * r
+    phys = jnp.take_along_axis(table, (pos // bs)[:, None], axis=1)[:, 0]
+    off = pos % bs
+    old = pool[layer, phys, off // r].reshape(B, r, kv * hd)
+    mine = (jnp.arange(r) == (off % r)[:, None])[:, :, None]     # (B, r, 1)
+    tok = new.reshape(B, 1, kv * hd).astype(pool.dtype)
+    return pool.at[layer, phys, off // r].set(
+        jnp.where(mine, tok, old).reshape(B, lanes)
+    )
 
 
 def paged_set_row(
@@ -341,7 +420,7 @@ def paged_copy_block(
     dst = jnp.asarray(dst, jnp.int32)
 
     def cow(pool):
-        blk = jnp.take(pool, src, axis=1)  # (L, bs, kv, hd)
+        blk = jnp.take(pool, src, axis=1)  # (L, bs // r, r * kv * hd)
         return jax.lax.dynamic_update_slice_in_dim(pool, blk[:, None], dst, axis=1)
 
     row = jnp.asarray(row, jnp.int32)

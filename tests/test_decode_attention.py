@@ -26,8 +26,12 @@ from repro.kernels.flash_attention.ref import (
     decode_attention_paged_ref,
     decode_attention_ref,
 )
+from repro.serve import kvcache
 
 KEY = jax.random.PRNGKey(0)
+# paged cases read one layer of stacked pools; every layer holds other
+# keys, so a wrong layer offset shows
+N_LAYERS, LAYER = 3, 1
 
 
 def _qkv(B, S, KV, G, d, dtype=jnp.float32):
@@ -167,12 +171,19 @@ def test_decode_matches_engine_mask_sliding_window_ring(new_len):
 
 
 def _paged_inputs(rng, B, KV, G, d, bs, n_blk, num_blocks, *, alias=False):
-    """Random pool + per-row block tables.  Physical order is shuffled (a
-    row's chain is non-monotonic in pool order) and, with ``alias=True``,
-    rows share leading blocks like prefix-cached requests do."""
+    """Random stacked pools, packed as the serve engine packs them, plus
+    per-row block tables.  Physical order is shuffled (a row's chain is
+    non-monotonic in pool order) and, with ``alias=True``, rows share
+    leading blocks like prefix-cached requests do."""
     q = jax.random.normal(KEY, (B, KV, G, d))
-    kpool = jax.random.normal(jax.random.fold_in(KEY, 1), (num_blocks, bs, KV, d))
-    vpool = jax.random.normal(jax.random.fold_in(KEY, 2), (num_blocks, bs, KV, d))
+    r = kvcache.tokens_per_row(KV, d, bs)
+    blocks = (N_LAYERS, num_blocks, bs, KV, d)
+    kpool = kvcache.pack_blocks(
+        jax.random.normal(jax.random.fold_in(KEY, 1), blocks), r
+    )
+    vpool = kvcache.pack_blocks(
+        jax.random.normal(jax.random.fold_in(KEY, 2), blocks), r
+    )
     if alias:
         shared = rng.permutation(num_blocks)[: n_blk // 2]
         tables = np.stack([
@@ -191,6 +202,49 @@ def _paged_inputs(rng, B, KV, G, d, bs, n_blk, num_blocks, *, alias=False):
     return q, kpool, vpool, jnp.asarray(tables, jnp.int32)
 
 
+def _dense_of(pool, tables, KV, d):
+    """A row's logical keys out of layer LAYER of a packed pool:
+    (B, n_blk * bs, KV, d)."""
+    blocks = kvcache.unpack_blocks(pool[LAYER], KV, d)    # (N, bs, KV, d)
+    return jnp.take(blocks, tables, axis=0).reshape(tables.shape[0], -1, KV, d)
+
+
+@pytest.mark.parametrize(
+    "KV,d,bs,r",
+    [(5, 64, 128, 2), (8, 128, 128, 1), (2, 16, 8, 4), (1, 8, 8, 8),
+     (3, 8, 4, 4), (2, 16, 2, 2)],
+)
+def test_tokens_per_row(KV, d, bs, r):
+    """A packed row is whole 128-lane tiles where the block allows it:
+    smollm's (5, 64) packs two tokens into 640 lanes, (8, 128) needs no
+    packing, and small test shapes get a divisor of the block size."""
+    got = kvcache.tokens_per_row(KV, d, bs)
+    assert got == r
+    assert bs % got == 0
+    if got == 128 // np.gcd(KV * d, 128):
+        assert (got * KV * d) % 128 == 0
+
+
+@pytest.mark.parametrize("KV,d,bs", [(5, 64, 128), (8, 128, 16), (2, 16, 8), (3, 8, 4)])
+def test_pack_unpack_round_trip(KV, d, bs):
+    """Packing is a pure reshape of (bs, KV, d): token t, head h, dim e sits
+    at row t // r, lane (t % r) * KV * d + h * d + e, and unpacking gives
+    the blocks back bitwise; token_slot names the same lanes."""
+    r = kvcache.tokens_per_row(KV, d, bs)
+    x = jax.random.normal(KEY, (2, 3, bs, KV, d)).astype(jnp.bfloat16)
+    packed = kvcache.pack_blocks(x, r)
+    assert packed.shape == (2, 3, bs // r, r * KV * d)
+    assert np.array_equal(
+        np.asarray(kvcache.unpack_blocks(packed, KV, d)), np.asarray(x)
+    )
+    xn, pn = np.asarray(x, np.float32), np.asarray(packed, np.float32)
+    for t in (0, bs // 2 + 1, bs - 1):
+        row, lanes = kvcache.token_slot(t, KV, d, bs)
+        assert np.array_equal(pn[1, 2, row, lanes], xn[1, 2, t].reshape(-1))
+        h, e = KV - 1, d - 1
+        assert pn[1, 2, t // r, (t % r) * KV * d + h * d + e] == xn[1, 2, t, h, e]
+
+
 @pytest.mark.parametrize("KV,G", [(1, 1), (1, 4), (2, 2), (3, 1), (2, 4)])
 def test_paged_kernel_gqa_vs_dense_oracle(KV, G):
     """Random non-monotonic block tables, every GQA grouping: the kernel
@@ -199,9 +253,9 @@ def test_paged_kernel_gqa_vs_dense_oracle(KV, G):
     rng = np.random.default_rng(0)
     q, kpool, vpool, tables = _paged_inputs(rng, B, KV, G, d, bs, n_blk, 12)
     lengths = jnp.asarray([3, 17, 32], jnp.int32)
-    want = decode_attention_paged_ref(q, kpool, vpool, tables, lengths)
+    want = decode_attention_paged_ref(q, kpool, vpool, tables, lengths, LAYER)
     got = decode_attention_paged(
-        q, kpool, vpool, tables, lengths, impl="pallas", interpret=True
+        q, kpool, vpool, tables, lengths, LAYER, impl="pallas", interpret=True
     )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
@@ -219,10 +273,10 @@ def test_paged_kernel_windows_and_splits(window, bs):
     q, kpool, vpool, tables = _paged_inputs(rng, B, KV, G, d, bs, n_blk, 10)
     lengths = jnp.asarray([5, 29], jnp.int32)
     want = decode_attention_paged_ref(
-        q, kpool, vpool, tables, lengths, window=window
+        q, kpool, vpool, tables, lengths, LAYER, window=window
     )
     got = decode_attention_paged(
-        q, kpool, vpool, tables, lengths, window=window,
+        q, kpool, vpool, tables, lengths, LAYER, window=window,
         impl="pallas", interpret=True,
     )
     np.testing.assert_allclose(
@@ -243,9 +297,9 @@ def test_paged_aliased_prefix_blocks_rows_agree():
     q = q.at[3].set(q[2])
     tables = tables.at[3].set(tables[2])
     lengths = jnp.asarray([7, 25, 13, 13], jnp.int32)
-    want = decode_attention_paged_ref(q, kpool, vpool, tables, lengths)
+    want = decode_attention_paged_ref(q, kpool, vpool, tables, lengths, LAYER)
     got = decode_attention_paged(
-        q, kpool, vpool, tables, lengths, impl="pallas", interpret=True
+        q, kpool, vpool, tables, lengths, LAYER, impl="pallas", interpret=True
     )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
@@ -263,16 +317,18 @@ def test_paged_twin_matches_kernel_and_contiguous(impl):
     q, kpool, vpool, tables = _paged_inputs(rng, B, KV, G, d, bs, n_blk, 16)
     lengths = jnp.asarray([2, 19, 32], jnp.int32)
     kw = dict(impl=impl, interpret=(impl == "pallas") or None)
-    got = decode_attention_paged(q, kpool, vpool, tables, lengths, **kw)
-    k_dense = jnp.take(kpool, tables, axis=0).reshape(B, n_blk * bs, KV, d)
-    v_dense = jnp.take(vpool, tables, axis=0).reshape(B, n_blk * bs, KV, d)
+    got = decode_attention_paged(q, kpool, vpool, tables, lengths, LAYER, **kw)
+    k_dense = _dense_of(kpool, tables, KV, d)
+    v_dense = _dense_of(vpool, tables, KV, d)
     dense_twin = decode_attention_xla(q, k_dense, v_dense, lengths, bk=bs)
     if impl == "xla":
         assert np.array_equal(np.asarray(got), np.asarray(dense_twin))
         assert np.array_equal(
             np.asarray(got),
             np.asarray(
-                decode_attention_paged_xla(q, kpool, vpool, tables, lengths)
+                decode_attention_paged_xla(
+                    q, kpool, vpool, tables, lengths, LAYER
+                )
             ),
         )
     else:
@@ -310,8 +366,8 @@ def test_paged_table_contents_do_not_recompile():
     q, kpool, vpool, tables = _paged_inputs(rng, B, KV, G, d, bs, n_blk, 12)
 
     fn = jax.jit(
-        lambda q, kp, vp, t, lens: decode_attention_paged(
-            q, kp, vp, t, lens, impl="pallas", interpret=True
+        lambda q, kp, vp, t, lens, li: decode_attention_paged(
+            q, kp, vp, t, lens, li, impl="pallas", interpret=True
         )
     )
     for seed, (a, b) in [(0, (1, 2)), (1, (7, 31)), (2, (32, 15))]:
@@ -322,7 +378,10 @@ def test_paged_table_contents_do_not_recompile():
             ]),
             jnp.int32,
         )
-        fn(q, kpool, vpool, t, jnp.asarray([a, b], jnp.int32)).block_until_ready()
+        fn(
+            q, kpool, vpool, t, jnp.asarray([a, b], jnp.int32),
+            jnp.int32(seed % N_LAYERS),
+        ).block_until_ready()
     if hasattr(fn, "_cache_size"):
         assert fn._cache_size() == 1
 

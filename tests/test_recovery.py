@@ -22,7 +22,7 @@ import pytest
 
 from repro.arch.model_zoo import build
 from repro.configs.registry import get
-from repro.serve import chaos, recovery
+from repro.serve import chaos, kvcache, recovery
 from repro.serve.engine import Engine, Request, RequestStatus, ServeConfig
 
 MAX_LEN = 64
@@ -271,9 +271,12 @@ def test_nan_guard_quarantines_poisoned_request(smol, layout):
     if layout == "paged":
         row = eng._rows[slot]
         pos = row.plen + st.emitted - 2  # last decode-written position
+        at, lanes = kvcache.token_slot(
+            pos % BS, cfg.n_kv_heads, cfg.resolved_head_dim, BS
+        )
         eng.caches["kpool"] = (
             eng.caches["kpool"]
-            .at[:, row.blocks[pos // BS], pos % BS]
+            .at[:, row.blocks[pos // BS], at, lanes]
             .set(jnp.nan)
         )
     else:
@@ -308,8 +311,9 @@ def test_kv_checksum_detects_finite_corruption(smol, paged_oracle):
         eng.step()
     slot = eng._slot_of(1)
     row = eng._rows[slot]
+    at, lanes = kvcache.token_slot(0, cfg.n_kv_heads, cfg.resolved_head_dim, BS)
     eng.caches["vpool"] = (
-        eng.caches["vpool"].at[:, row.blocks[0], 0].add(1.0)
+        eng.caches["vpool"].at[:, row.blocks[0], at, lanes].add(1.0)
     )
     eng.step()
     assert eng.status(1) == RequestStatus.FAILED

@@ -14,6 +14,7 @@ while a module is imported or tests are collected.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -100,18 +101,21 @@ def test_contiguous_decode_compiles(spec, bk):
 
 def test_paged_decode_compiles(spec):
     from repro.kernels.flash_attention.ops import decode_attention_paged
+    from repro.serve.kvcache import tokens_per_row
 
     n_blk = MAX_LEN // BLOCK
-    pool = (SLOTS * n_blk + 1, BLOCK, KV, D)
+    r = tokens_per_row(KV, D, BLOCK)
+    pool = (2, SLOTS * n_blk + 1, BLOCK // r, r * KV * D)
     text = _compiled_text(
-        lambda q, kp, vp, t, n: decode_attention_paged(
-            q, kp, vp, t, n, impl="pallas", interpret=False
+        lambda q, kp, vp, t, n, li: decode_attention_paged(
+            q, kp, vp, t, n, li, impl="pallas", interpret=False
         ),
         spec((SLOTS, KV, G, D)),
         spec(pool),
         spec(pool),
         spec((SLOTS, n_blk), jnp.int32),
         spec((SLOTS,), jnp.int32),
+        spec((), jnp.int32),
     )
     assert _kernel_names(text) == ["decode_attention_paged"]
 
@@ -147,6 +151,112 @@ def test_engine_decode_program_names_its_kernel(one_chip, monkeypatch):
     monkeypatch.undo()
     text = lowered.compile().as_text()
     assert _kernel_names(text) == ["decode_attention_paged"]
+
+
+_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2,
+          "f16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8}
+_ARRAY = re.compile(r"\b([a-z]+[0-9]+|pred)\[([0-9,]*)\]")
+_OPCODE = re.compile(r"(?<=[\s}\]])([a-z][a-z0-9-]*)\(")
+
+
+def _largest_array(shape: str) -> tuple[int, tuple[int, ...]]:
+    """(bytes, dims) of the largest array in an HLO result type (a tuple's
+    elements are separate buffers)."""
+    arrays = [(0, ())]
+    for dtype, dims in _ARRAY.findall(shape):
+        dims = tuple(int(x) for x in dims.split(",") if x)
+        n = _BYTES.get(dtype, 1)
+        for x in dims:
+            n *= x
+        arrays.append((n, dims))
+    return max(arrays)
+
+
+def _moves(text: str) -> list[tuple[str, str, int, tuple[int, ...]]]:
+    """(instruction, opcode, bytes moved, their dims) of every copy,
+    dynamic-slice and dynamic-update-slice in a compiled program, fused
+    ones included.  A copy or slice moves its result; an in-place
+    dynamic-update-slice's result is the buffer it updates, so what it
+    moves is its update (operand 1)."""
+    shapes, found = {}, []
+    for line in text.splitlines():
+        if " = " not in line:
+            continue
+        name, rest = line.split(" = ", 1)
+        name = name.split()[-1].lstrip("%")
+        m = _OPCODE.search(rest)
+        if m is None:
+            continue
+        shapes[name] = rest[: m.start()]
+        op = m.group(1)
+        if op in ("copy", "copy-start", "dynamic-slice"):
+            found.append((name, op, name))
+        elif op == "dynamic-update-slice":
+            operands = rest[m.end():].split(")", 1)[0].split(",")
+            found.append((name, op, operands[1].split("*/")[-1].strip().lstrip("%")))
+    return [
+        (name, op, *_largest_array(shapes.get(ref, ""))) for name, op, ref in found
+    ]
+
+
+def test_engine_paged_programs_keep_the_pool_in_place(one_chip, monkeypatch):
+    """At the smollm-360m chat cell's shapes (32 layers, 1101 blocks of
+    128, 64 slots, max_len 4096) the engine's decode, block-pack and
+    copy-on-write programs move no buffer the size of a layer's KV pool:
+    the decode program carries the stacked pools through its layer loop
+    and the kernel reads them in place.  Only shapes are given; nothing is
+    allocated at the cell's size."""
+    from repro.arch.model_zoo import build
+    from repro.configs.registry import get
+    from repro.serve import kvcache
+    from repro.serve.engine import Engine, KVConfig, SchedulerConfig, ServeConfig
+
+    slots, max_len, blocks, bs = 64, 4096, 1101, 128
+    cfg = get("smollm-360m")
+    params = jax.eval_shape(build(cfg).init, jax.random.PRNGKey(0))
+    eng = Engine(cfg, params, ServeConfig(
+        max_len=max_len,
+        scheduler=SchedulerConfig(batch=slots),
+        kv=KVConfig(layout="paged", block_size=bs, num_blocks=4),
+    ))
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+        )
+
+    caches = on_chip(jax.eval_shape(
+        lambda: kvcache.build_paged_caches(cfg, slots, max_len, blocks, bs)
+    ))
+    assert caches["kpool"].shape == (32, blocks, 64, 640)
+    layer_pool = blocks * bs * cfg.n_kv_heads * cfg.resolved_head_dim * 2
+    scratch = on_chip(jax.eval_shape(lambda: kvcache.build_caches(cfg, 1, max_len)))
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    rows = on_chip(jnp.zeros((slots,), jnp.int32))
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    decode = eng._decode.lower(
+        on_chip(params), on_chip(jnp.zeros((slots, 1), jnp.int32)), caches,
+        on_chip(jnp.zeros((slots, 2), jnp.uint32)), rows,
+    ).compile()
+    pack = eng._pack_row.lower(
+        caches, {"k": scratch["k"], "v": scratch["v"]}, i32, i32,
+        on_chip(jnp.zeros((4,), jnp.int32)),
+    ).compile()
+    cow = eng._cow.lower(caches, i32, i32, i32, i32).compile()
+    monkeypatch.undo()
+
+    # a weight's relayout (the tied embedding, read by the gather and the
+    # head) is not a pool move; every other move that big is
+    weights = {tuple(sorted(a.shape)) for a in jax.tree.leaves(params)}
+    for prog in (decode, pack, cow):
+        big = [
+            m for m in _moves(prog.as_text())
+            if m[2] >= layer_pool and tuple(sorted(m[3])) not in weights
+        ]
+        assert not big, big
+    assert decode.memory_analysis().temp_size_in_bytes < 1 << 30
+    assert _kernel_names(decode.as_text()) == ["decode_attention_paged"]
 
 
 def test_prefill_flash_attention_compiles(spec):
