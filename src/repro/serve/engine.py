@@ -1619,7 +1619,7 @@ class Engine:
         C = self._chunk
         plen = len(info.prompt)
         end = min(plen, lane.filled + C)
-        with _span("serve.lane.chunk", rid=lane.rid, filled=end):
+        with _span("serve.lane.chunk", rid=lane.rid, start=lane.filled, filled=end):
             toks = np.zeros((1, C), np.int32)
             toks[0, : end - lane.filled] = info.prompt[lane.filled : end]
             li = np.asarray([min(C - 1, max(0, plen - 1 - lane.filled))], np.int32)

@@ -120,9 +120,14 @@ def test_admission_spans_sit_in_schedule_and_name_their_request(traced):
     # the second prompt's blocks are all aliased: nothing of it is packed
     assert [s.stats["packed"] for s in by["serve.lane.install"]] == [3, 0, 2]
     lens = [len(p) for p in prompts(eng.cfg.vocab)]
-    chunks = [(s.stats["rid"], s.stats["filled"]) for s in by["serve.lane.chunk"]]
-    assert chunks == [(r, min(f, n)) for r, n in zip(rids, lens)
+    # each chunk names the prompt tokens in the scratch before and after it:
+    # its own real tokens are [start, filled), the last chunk's padding not
+    chunks = [(s.stats["rid"], s.stats["start"], s.stats["filled"])
+              for s in by["serve.lane.chunk"]]
+    assert chunks == [(r, f - CHUNK, min(f, n)) for r, n in zip(rids, lens)
                       for f in range(CHUNK, n + CHUNK, CHUNK)]
+    real = [min(CHUNK, n - s) for n in lens for s in range(0, n, CHUNK)]
+    assert [f - s for _, s, f in chunks] == real
     assert [s.stats["rid"] for s in spans if s.name == "serve.submit"] == rids
     assert [s.stats["n"] for s in spans if s.name == "serve.cow"] == [1]
 

@@ -3,8 +3,9 @@
 The TPU compiler is installed with jaxlib, and it compiles for a chip
 that is described and not attached.  Interpret mode cannot see what it
 refuses (block shapes not aligned to the (8, 128) tiling, VMEM overruns),
-so each case compiles one kernel at smollm-360m serving shapes and checks
-that the Mosaic kernel (``tpu_custom_call``) is in the compiled program.
+so each case compiles one kernel at smollm-360m or granite-8b serving
+shapes and checks that the Mosaic kernel (``tpu_custom_call``) is in the
+compiled program, and the engine's programs at each benchmark cell's shapes.
 
 The topology is described inside a fixture only: describing it loads the
 TPU library, which one process may hold at a time, so it must never happen
@@ -13,18 +14,38 @@ while a module is imported or tests are collected.
 
 from __future__ import annotations
 
+import dataclasses
+import json
 import os
 import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+ROOT = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT))  # the checkout, for `bench`
+
 # smollm-360m: 15 query heads over 5 kv heads of width 64
 KV, G, D = 5, 3, 64
 SLOTS, MAX_LEN, BLOCK = 8, 2048, 16
 D_MODEL, D_FF, VOCAB = 960, 2560, 49152
+# the benchmark's configuration files: the engine's programs and the paged
+# and prefill kernels compile at each one's serving shapes
+CONFIGS = sorted((ROOT / "bench" / "configs").glob("*.json"))
+CELLS = [pytest.param(json.loads(p.read_text()), id=p.stem) for p in CONFIGS]
+# the v5e's device memory as its runtime reports it (``bytes_limit`` of
+# ``memory_stats()``); a cell sizes its pool to 0.9 of it
+BYTES_LIMIT = 16_909_336_064
+
+
+def _heads(sizes: dict) -> tuple[int, int, int]:
+    """(kv heads, query heads a kv head serves, head width) of a config file."""
+    m = sizes["model"]
+    return m["n_kv_heads"], m["n_heads"] // m["n_kv_heads"], m["head_dim"]
 
 
 @pytest.fixture(scope="module")
@@ -99,22 +120,25 @@ def test_contiguous_decode_compiles(spec, bk):
     assert _kernel_names(text) == ["decode_attention"]
 
 
-def test_paged_decode_compiles(spec):
+@pytest.mark.parametrize("sizes", CELLS)
+def test_paged_decode_compiles(spec, sizes):
     from repro.kernels.flash_attention.ops import decode_attention_paged
     from repro.serve.kvcache import tokens_per_row
 
-    n_blk = MAX_LEN // BLOCK
-    r = tokens_per_row(KV, D, BLOCK)
-    pool = (2, SLOTS * n_blk + 1, BLOCK // r, r * KV * D)
+    kv, g, d = _heads(sizes)
+    slots, max_len, block = (sizes["serve"][k] for k in ("slots", "max_len", "block_size"))
+    n_blk = max_len // block
+    r = tokens_per_row(kv, d, block)
+    pool = (2, slots * n_blk + 1, block // r, r * kv * d)
     text = _compiled_text(
         lambda q, kp, vp, t, n, li: decode_attention_paged(
             q, kp, vp, t, n, li, impl="pallas", interpret=False
         ),
-        spec((SLOTS, KV, G, D)),
+        spec((slots, kv, g, d)),
         spec(pool),
         spec(pool),
-        spec((SLOTS, n_blk), jnp.int32),
-        spec((SLOTS,), jnp.int32),
+        spec((slots, n_blk), jnp.int32),
+        spec((slots,), jnp.int32),
         spec((), jnp.int32),
     )
     assert _kernel_names(text) == ["decode_attention_paged"]
@@ -199,26 +223,33 @@ def _moves(text: str) -> list[tuple[str, str, int, tuple[int, ...]]]:
     ]
 
 
-def test_engine_paged_programs_keep_the_pool_in_place(one_chip, monkeypatch):
-    """At the smollm-360m chat cell's shapes (32 layers, 1101 blocks of
-    128, 64 slots, max_len 4096) the engine's decode, block-pack and
+@pytest.mark.parametrize("sizes", CELLS)
+def test_engine_paged_programs_keep_the_pool_in_place(one_chip, monkeypatch, sizes):
+    """At a benchmark cell's shapes (its configuration file's layers, widths,
+    slots, blocks and max_len) the engine's decode, block-pack and
     copy-on-write programs move no buffer the size of a layer's KV pool:
-    the decode program carries the stacked pools through its layer loop
-    and the kernel reads them in place.  Only shapes are given; nothing is
-    allocated at the cell's size."""
-    from repro.arch.model_zoo import build
-    from repro.configs.registry import get
-    from repro.serve import kvcache
-    from repro.serve.engine import Engine, KVConfig, SchedulerConfig, ServeConfig
+    the decode program carries the stacked pools through its layer loop and
+    the kernel reads them in place.
 
-    slots, max_len, blocks, bs = 64, 4096, 1101, 128
-    cfg = get("smollm-360m")
+    Weights and pool fit 0.9 of the chip's memory, the rule each cell's pool
+    is sized by, and with every other live buffer as compiled here (the
+    prefill scratch, the decode and chunk programs' temporaries) the whole
+    of it.  The 0.9 rule is not applied to the compiled temporaries: they
+    are not what the chip holds.  At granite-8b's widths the chunk program's
+    compile to 0.59 GB where the v5e's peak shows about 0.33 GB, so the
+    chip's own peak (0.8998 of the limit) is what sizes the pool.  Only
+    shapes are given; nothing is allocated at the cell's size."""
+    from bench import drive
+    from repro.arch.model_zoo import build
+    from repro.serve import kvcache
+    from repro.serve.engine import Engine
+
+    cfg, scfg = drive.engine_config(sizes)
+    slots, max_len, bs = scfg.batch, scfg.max_len, scfg.block_size
+    blocks = scfg.num_blocks
     params = jax.eval_shape(build(cfg).init, jax.random.PRNGKey(0))
-    eng = Engine(cfg, params, ServeConfig(
-        max_len=max_len,
-        scheduler=SchedulerConfig(batch=slots),
-        kv=KVConfig(layout="paged", block_size=bs, num_blocks=4),
-    ))
+    eng = Engine(cfg, params, dataclasses.replace(
+        scfg, kv=dataclasses.replace(scfg.kv, num_blocks=4)))
 
     def on_chip(tree):
         return jax.tree.map(
@@ -228,9 +259,11 @@ def test_engine_paged_programs_keep_the_pool_in_place(one_chip, monkeypatch):
     caches = on_chip(jax.eval_shape(
         lambda: kvcache.build_paged_caches(cfg, slots, max_len, blocks, bs)
     ))
-    assert caches["kpool"].shape == (32, blocks, 64, 640)
-    layer_pool = blocks * bs * cfg.n_kv_heads * cfg.resolved_head_dim * 2
-    scratch = on_chip(jax.eval_shape(lambda: kvcache.build_caches(cfg, 1, max_len)))
+    kv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
+    r = kvcache.tokens_per_row(kv, hd, bs)
+    assert caches["kpool"].shape == (cfg.n_layers, blocks, bs // r, r * kv * hd)
+    layer_pool = blocks * bs * kv * hd * 2
+    scratch = on_chip(jax.eval_shape(eng._fresh_scratch))
     i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     rows = on_chip(jnp.zeros((slots,), jnp.int32))
 
@@ -238,6 +271,10 @@ def test_engine_paged_programs_keep_the_pool_in_place(one_chip, monkeypatch):
     decode = eng._decode.lower(
         on_chip(params), on_chip(jnp.zeros((slots, 1), jnp.int32)), caches,
         on_chip(jnp.zeros((slots, 2), jnp.uint32)), rows,
+    ).compile()
+    chunk = eng._chunk_step.lower(
+        on_chip(params), on_chip(jnp.zeros((1, scfg.prefill_chunk), jnp.int32)), scratch,
+        on_chip(jnp.zeros((1,), jnp.int32)), on_chip(jnp.zeros((2,), jnp.uint32)),
     ).compile()
     pack = eng._pack_row.lower(
         caches, {"k": scratch["k"], "v": scratch["v"]}, i32, i32,
@@ -258,15 +295,25 @@ def test_engine_paged_programs_keep_the_pool_in_place(one_chip, monkeypatch):
     assert decode.memory_analysis().temp_size_in_bytes < 1 << 30
     assert _kernel_names(decode.as_text()) == ["decode_attention_paged"]
 
+    def nbytes(tree):
+        return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
 
-def test_prefill_flash_attention_compiles(spec):
+    held = nbytes(params) + nbytes(caches)
+    assert held <= 0.9 * BYTES_LIMIT, held
+    temps = sum(p.memory_analysis().temp_size_in_bytes for p in (decode, chunk))
+    assert held + nbytes(scratch) + temps <= BYTES_LIMIT, (held, nbytes(scratch), temps)
+
+
+@pytest.mark.parametrize("sizes", CELLS)
+def test_prefill_flash_attention_compiles(spec, sizes):
     from repro.kernels.flash_attention.ops import flash_attention
 
+    kv, g, d = _heads(sizes)
     text = _compiled_text(
         lambda q, k, v: flash_attention(q, k, v, interpret=False),
-        spec((1, MAX_LEN, KV, G, D)),
-        spec((1, MAX_LEN, KV, D)),
-        spec((1, MAX_LEN, KV, D)),
+        spec((1, MAX_LEN, kv, g, d)),
+        spec((1, MAX_LEN, kv, d)),
+        spec((1, MAX_LEN, kv, d)),
     )
     assert "tpu_custom_call" in text
 
