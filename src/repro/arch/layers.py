@@ -264,6 +264,7 @@ def multihead_attention(
         ctx = decode_attention_paged(
             qg,
             kpool, vpool, cache["table"], p_ins + 1, li,
+            work=cache["work"],
             # supports_paged admits only all-global configs, so the scanned
             # per-layer window (traced here) is always the 2^30 sentinel
             window=None,

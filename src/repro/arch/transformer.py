@@ -260,9 +260,20 @@ class Model:
         pool would be sliced out and restacked every layer, a pool-sized
         copy each way.  Carried, each layer writes its token into the pools
         in place and the kernel reads layer ``li`` of them by index.  The
-        block table and lengths are the same for every layer."""
+        block table and lengths are the same for every layer, and so is the
+        kernel's work list, built here once.  A row whose table starts at
+        the sink holds no request: its length stays 0, so the kernel and
+        its XLA twin both read the one sink key, and the list gives it one
+        item."""
+        from repro.kernels.flash_attention.decode_attention import paged_work_list
+        from repro.serve.kvcache import SINK_BLOCK
+
         cfg = self.cfg
         table, length = caches["table"][0], caches["len"][0]
+        rows, lanes = caches["kpool"].shape[2:]
+        bs = rows * lanes // (cfg.n_kv_heads * cfg.resolved_head_dim)
+        # the kernel attends over the keys through this step's token
+        work = paged_work_list(table, length + 1, bs)
 
         def body(carry, p_w_i):
             x, kpool, vpool = carry
@@ -272,7 +283,7 @@ class Model:
                 trace.layer = li
             cache = {
                 "kpool": kpool, "vpool": vpool, "table": table,
-                "len": length, "layer": li,
+                "len": length, "layer": li, "work": work,
             }
             # paged caches exist only for all-global configs
             # (kvcache.supports_paged), so the ragged invariant is free
@@ -297,8 +308,10 @@ class Model:
         if trace is not None:
             trace.layer = None
             trace.flags.append(jnp.any(flags))
+        live = caches["table"][..., 0] != SINK_BLOCK
         new_caches = {
-            **caches, "kpool": kpool, "vpool": vpool, "len": caches["len"] + 1,
+            **caches, "kpool": kpool, "vpool": vpool,
+            "len": caches["len"] + live.astype(jnp.int32),
         }
         return x, new_caches, jnp.sum(auxs)
 

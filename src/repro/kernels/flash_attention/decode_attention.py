@@ -51,17 +51,22 @@ stacked **block pools** ``(L, num_blocks, bs // r, r * KV * d)`` — every
 layer's pool, packed ``r`` tokens to a row so a row is whole 128-lane tiles
 (``serve.kvcache.pack_blocks``) — and each row carries a **block table**
 ``(B, max_blocks)`` mapping its logical block ``j`` to a physical pool
-block.  The grid stays ``(batch, kv_splits)`` with ``kv_splits ==
-max_blocks``; the k/v index maps go through the table and a layer index —
-scalar-prefetch operands, so table contents and the layer never recompile
-— and dead splits alias to the row's last live **physical** block exactly
-like the dense variant.  Reading a layer by index, the kernel takes the
-decode program's pools as they are, with no per-layer slice.  The body
-takes each head's ``r`` lane slices of a packed block (one per token
-parity) through the same online-softmax update.  Because the KV split
-boundary is the block boundary, the paged recurrence visits the same
-logical key ranges in the same order as the dense kernel at ``bk ==
-block_size``; within a block the kernel sums the ``r`` token parities
+block.  The grid is one axis over a **work list** of live ``(row, logical
+block)`` items (:func:`paged_work_list`), rows in order, and its length is
+the list's traced length: a row of ``len`` tokens costs ``ceil(len/bs)``
+grid steps, a row that holds no request one, so the grid walks the
+blocks the rows hold and not the whole ``(batch, max_blocks)`` rectangle.
+The work list, its length, the table and a layer index are
+scalar-prefetch operands, so none of them recompiles; the index maps go
+from an item to its row and through the table to its physical block.  A
+row's items are consecutive, so its carry resets at its first block and
+its output is written once, at its last.  Reading a layer by index, the
+kernel takes the decode program's pools as they are, with no per-layer
+slice.  The body takes each head's ``r`` lane slices of a packed block
+(one per token parity) through the same online-softmax update.  Because
+the KV split boundary is the block boundary, the paged recurrence visits
+the same logical key ranges in the same order as the dense kernel at ``bk
+== block_size``; within a block the kernel sums the ``r`` token parities
 separately (so it matches the dense kernel to rounding, exactly at ``r ==
 1``), while the XLA twin unpacks the block and is bitwise equal to the
 dense twin, which is what lets the contiguous serve engine act as the
@@ -102,11 +107,13 @@ def _live_keys(k_idx, length, window):
 
 def _decode_step(
     length,                       # live keys of this row (SMEM scalar)
+    j,                            # which KV split of the row this step reads
+    last,                         # this step ends the row: finalize into o_ref
     q_ref,                        # (1, KV, G, d)
     parts,                        # h -> [(k (n, d), v (n, d), stride, first)]
     o_ref,                        # (1, KV, G, d)
     m_ref, l_ref, acc_ref,        # VMEM scratch: (KV, G), (KV, G), (KV, G, d) fp32
-    *, bk: int, n_k: int, scale: float, window: int | None,
+    *, bk: int, scale: float, window: int | None,
 ):
     """One KV split of one row, shared by the dense and paged kernels.  A
     static loop over kv heads runs the online-softmax update per head over
@@ -114,8 +121,9 @@ def _decode_step(
     the block's keys ``first, first + stride, ...``.  The dense kernel has
     one part per head (stride 1); the paged kernel one per token of a packed
     pool row.  The running max covers every part before any is exponentiated,
-    so the update is the one-part update with its sums split by part."""
-    j = pl.program_id(1)
+    so the update is the one-part update with its sums split by part.  The
+    caller names the split ``j`` and whether the row ends here: the dense
+    grid walks ``(row, split)``, the paged grid a list of work items."""
 
     @pl.when(j == 0)
     def _init():
@@ -158,7 +166,7 @@ def _decode_step(
                 ],
             )
 
-    @pl.when(j == n_k - 1)
+    @pl.when(last)
     def _store():
         finalize_out(o_ref, l_ref, acc_ref)
 
@@ -171,12 +179,16 @@ def _decode_scratch(KV: int, G: int, d: int) -> list:
     ]
 
 
-def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, *refs, **kw):
+def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, *refs, n_k: int, **kw):
     # lens_ref: SMEM (B,) int32 scalar-prefetch; k/v blocks (1, bk, KV, d)
     def parts(h):
         return [(k_ref[0, :, h, :], v_ref[0, :, h, :], 1, 0)]
 
-    _decode_step(lens_ref[pl.program_id(0)], q_ref, parts, *refs, window=None, **kw)
+    j = pl.program_id(1)
+    _decode_step(
+        lens_ref[pl.program_id(0)], j, j == n_k - 1, q_ref, parts, *refs,
+        window=None, **kw,
+    )
 
 
 def flash_decode_pallas(
@@ -275,13 +287,47 @@ def decode_attention_xla(
 # ------------------------------------------------------------ paged variants
 
 
-def _paged_decode_kernel(lens_ref, table_ref, layer_ref, q_ref, k_ref, v_ref,
-                         *refs, r: int, **kw):
-    # scalar prefetch (SMEM): lens (B,), table (B, n_blk), layer (1,) int32;
-    # the table and layer are only read by the index maps.  k/v blocks are
-    # one packed pool block (1, bs // r, r * KV * d): token t of the block,
-    # head h sits at row t // r, lanes (t % r) * KV * d + h * d.
+def paged_work_list(
+    tables: jax.Array,    # (B, n_blk) int32 logical -> physical block
+    lengths: jax.Array,   # (B,) int32 live tokens per row
+    bs: int,              # tokens per block
+) -> tuple[jax.Array, jax.Array]:
+    """The paged kernel's work items: ``(work, n_work)``.  Row ``b`` gets
+    ``n_b = ceil(clamp(len_b, 1, n_blk * bs) / bs)`` items, one per logical
+    block ``j < n_b`` it reads, encoded ``b * n_blk + j`` (the flat index
+    of its table entry), rows in order: the blocks the XLA twin walks, so
+    the two read the same keys.  Every row keeps at least one item, so the
+    kernel writes every output row.
+    ``n_work = sum(n_b)`` is traced; the array keeps its static size
+    ``B * n_blk``, its tail padded with the last item, so an index map read
+    one step ahead stays in range."""
+    B, n_blk = tables.shape
+    lengths = jnp.clip(lengths.astype(jnp.int32), 1, n_blk * bs)
+    n = (lengths + bs - 1) // bs
+    ends = jnp.cumsum(n)
+    n_work = ends[-1]
+    i = jnp.minimum(jnp.arange(B * n_blk, dtype=jnp.int32), n_work - 1)
+    # item i's row: the rows that end at or before it, one vector compare
+    b = jnp.sum(ends[None, :] <= i[:, None], axis=1, dtype=jnp.int32)
+    j = i - (ends[b] - n[b])
+    return b * n_blk + j, n_work
+
+
+def _paged_decode_kernel(work_ref, n_work_ref, lens_ref, table_ref, layer_ref,
+                         q_ref, k_ref, v_ref, *refs, r: int, n_blk: int, **kw):
+    # scalar prefetch (SMEM): work (W,), n_work (1,), lens (B,), table
+    # (B * n_blk,), layer (1,) int32; the table and layer are only read by
+    # the index maps.  Grid step i runs work item work[i] = b * n_blk + j.
+    # k/v blocks are one packed pool block (1, bs // r, r * KV * d): token
+    # t of the block, head h sits at row t // r, lanes (t % r) * KV * d + h * d.
     KV, d = q_ref.shape[1], q_ref.shape[3]
+    i = pl.program_id(0)
+    item = work_ref[i]
+    b = item // n_blk
+    # a row's items are consecutive: it ends where the next item's row
+    # differs, or at the list's last item (the padded tail repeats it)
+    nxt = work_ref[jnp.minimum(i + 1, work_ref.shape[0] - 1)]
+    last = (i == n_work_ref[0] - 1) | (nxt // n_blk != b)
 
     def parts(h):
         return [
@@ -294,7 +340,7 @@ def _paged_decode_kernel(lens_ref, table_ref, layer_ref, q_ref, k_ref, v_ref,
             for t in range(r)
         ]
 
-    _decode_step(lens_ref[pl.program_id(0)], q_ref, parts, *refs, **kw)
+    _decode_step(lens_ref[b], item % n_blk, last, q_ref, parts, *refs, **kw)
 
 
 def flash_decode_paged_pallas(
@@ -305,29 +351,35 @@ def flash_decode_paged_pallas(
     lengths: jax.Array,   # (B,) int32 live tokens per row (traced)
     layer: jax.Array,     # () int32 which layer's pool to read (traced)
     *,
+    work: tuple[jax.Array, jax.Array] | None = None,
     window: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
+    """``work`` is :func:`paged_work_list` of these tables and lengths,
+    which a caller running every layer builds once; without it the kernel
+    builds it."""
     B, KV, G, d = q.shape
     rows, lanes = kpool.shape[2:]
     r = lanes // (KV * d)
     bs = rows * r
     n_blk = tables.shape[1]
     scale = 1.0 / math.sqrt(d)
+    if work is None:
+        work = paged_work_list(tables, lengths, bs)
+    items, n_work = work
     lengths = jnp.clip(lengths.astype(jnp.int32), 1, n_blk * bs)
-    tables = tables.astype(jnp.int32)
+    tables = tables.astype(jnp.int32).reshape(-1)
     layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
 
-    def kv_block(b, j, lens, tabs, li):
-        last = last_live_block(lens[b], bs)
-        return (li[0], tabs[b, jnp.minimum(j, last)], 0, 0)
+    def kv_block(i, items, n_work, lens, tabs, li):
+        return (li[0], tabs[items[i]], 0, 0)
 
-    def row(b, j, lens, tabs, li):
-        return (b, 0, 0, 0)
+    def row(i, items, n_work, lens, tabs, li):
+        return (items[i] // n_blk, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, n_blk),
+        num_scalar_prefetch=5,
+        grid=(n_work,),
         in_specs=[
             pl.BlockSpec((1, KV, G, d), row),
             pl.BlockSpec((None, 1, rows, lanes), kv_block),
@@ -338,7 +390,7 @@ def flash_decode_paged_pallas(
     )
     kern = functools.partial(
         _paged_decode_kernel,
-        r=r, bk=bs, n_k=n_blk, scale=scale, window=window,
+        r=r, n_blk=n_blk, bk=bs, scale=scale, window=window,
     )
     return pl.pallas_call(
         kern,
@@ -346,7 +398,8 @@ def flash_decode_paged_pallas(
         out_shape=jax.ShapeDtypeStruct((B, KV, G, d), q.dtype),
         interpret=interpret,
         name="decode_attention_paged",
-    )(lengths, tables, layer, q, kpool, vpool)
+    )(items, jnp.reshape(n_work, (1,)).astype(jnp.int32), lengths, tables, layer,
+      q, kpool, vpool)
 
 
 def decode_attention_paged_xla(

@@ -213,13 +213,15 @@ def decode_attention_paged(
     lengths: jax.Array,   # (B,) int32 live tokens per row (traced)
     layer: jax.Array,     # () int32 which layer's pool to read (traced)
     *,
+    work: tuple[jax.Array, jax.Array] | None = None,
     window: int | None = None,
     impl: str | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Block-table-indirect ragged flash-decoding over layer ``layer`` of
     the stacked, packed pools (``serve.kvcache.pack_blocks``); returns
-    (B, KV, G, d).
+    (B, KV, G, d).  ``work`` is the kernel's work list
+    (``decode_attention.paged_work_list``); the XLA twin has none.
 
     The KV split size is the pool's block size (splits are physical
     blocks), so there is no ``bk`` knob: the logical reduction order is
@@ -237,7 +239,7 @@ def decode_attention_paged(
         q,
         lambda qp: flash_decode_paged_pallas(
             qp, kpool, vpool, tables, lengths, layer,
-            window=window, interpret=interp,
+            work=work, window=window, interpret=interp,
         ),
         interp,
     )

@@ -83,9 +83,10 @@ is tiled, in order, by ``serve.schedule`` (deadlines, preemption,
 admission, copy-on-write, building and uploading the decode inputs; its
 children are ``serve.lane.start``/``.chunk``/``.install``, ``serve.admit``
 and ``serve.cow``), ``serve.decode`` (the decode dispatch up to its tokens
-on the host) and ``serve.emit`` (quarantine, token callbacks, finishing and
-eviction), then ``serve.journal`` when a RecoveryManager is attached.  A
-step with no live slot after admission stops after ``serve.schedule``.
+on the host; a paged engine's carries ``work``, the decode kernel's work
+items per layer) and ``serve.emit`` (quarantine, token callbacks, finishing
+and eviction), then ``serve.journal`` when a RecoveryManager is attached.
+A step with no live slot after admission stops after ``serve.schedule``.
 With no trace running a span costs a native check and a small Python
 object.
 """
@@ -968,6 +969,8 @@ class Engine:
             "sdc_retried": 0,   # abft: oracle-substrate step re-executions
             "prompt_tokens": 0,      # paged: prompt tokens of committed admissions
             "prefix_hit_tokens": 0,  # ... of them aliased through the prefix index
+            "decode_work_items": 0,  # paged: the decode kernel's items per layer
+            "decode_grid_full": 0,   # ... and its whole (slots x blocks) grid
         }
 
         model, impl, axes = self.model, self._impl, self._axes
@@ -2027,7 +2030,9 @@ class Engine:
                 self._slots[s].sdc_retries += 1
         if self._rewind is None:
             self._rewind = jax.jit(
-                lambda c: {**c, "len": c["len"] - 1}, donate_argnums=(0,)
+                # a paged row that holds no request stays at length 0
+                lambda c: {**c, "len": jnp.maximum(c["len"] - 1, 0)},
+                donate_argnums=(0,),
             )
         if self._retry_fn is None:
             self._retry_fn = (
@@ -2142,7 +2147,14 @@ class Engine:
                     self._touched.add(row.blocks[(row.plen + st.emitted - 1) // bs])
             toks = jnp.asarray(self._cur_tok[:, None])
             jkeys, jts = jnp.asarray(keys), jnp.asarray(ts)
-        with _span("serve.decode", rows=len(self._slots)):
+            args = {"rows": len(self._slots)}
+            if self._paged:
+                args["work"] = self._decode_work()
+                self.stats["decode_work_items"] += args["work"]
+                self.stats["decode_grid_full"] += (
+                    B * self.scfg.max_len // self.scfg.block_size
+                )
+        with _span("serve.decode", **args):
             if self._abft:
                 fault = self._fault.copy()
                 from repro.kernels.abft import FAULT_SCRUB
@@ -2210,6 +2222,19 @@ class Engine:
                 self._audit_kv_checksums()
             span.set_metadata(finished=len(finished))
         return True
+
+    def _decode_work(self) -> int:
+        """The paged decode kernel's work items per layer this step, from
+        the host's own rows (``decode_attention.paged_work_list`` counts
+        the same on the device from the lengths): one per block a live row
+        reads through its newest token, at position ``plen + emitted - 1``,
+        and one for each row that holds no request, whose length is 0."""
+        bs = self.scfg.block_size
+        live = sum(
+            -(-(self._rows[s].plen + st.emitted) // bs)
+            for s, st in self._slots.items()
+        )
+        return self.scfg.batch - len(self._slots) + live
 
     def pop_result(self, rid: int) -> RequestResult:
         """Take a request's :class:`RequestResult`.  Terminal requests are

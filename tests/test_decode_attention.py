@@ -16,6 +16,7 @@ from repro.arch.attention import dense_attention
 from repro.kernels.flash_attention.decode_attention import (
     decode_attention_paged_xla,
     decode_attention_xla,
+    paged_work_list,
 )
 from repro.kernels.flash_attention.ops import (
     decode_attention,
@@ -337,6 +338,72 @@ def test_paged_twin_matches_kernel_and_contiguous(impl):
         )
 
 
+def _np_work(lens, bs, n_blk):
+    """The work list counted row by row: a row reads its blocks through
+    its clamped length."""
+    items = []
+    for b, n in enumerate(lens):
+        n_b = -(-min(max(n, 1), n_blk * bs) // bs)
+        items += [b * n_blk + j for j in range(n_b)]
+    return items
+
+
+def _sink_tables(rng, dead, n_blk, num_blocks):
+    """Live rows own distinct blocks past the sink; dead rows point every
+    logical block at the sink, as an evicted engine row does."""
+    owned = rng.permutation(np.arange(1, num_blocks))
+    return jnp.asarray(np.stack([
+        np.full((n_blk,), kvcache.SINK_BLOCK)
+        if is_dead else owned[b * n_blk:(b + 1) * n_blk]
+        for b, is_dead in enumerate(dead)
+    ]), jnp.int32)
+
+
+@pytest.mark.parametrize(
+    "lens,dead",
+    [
+        ([3, 17, 9, 30], [0, 0, 0, 0]),        # ragged live rows
+        ([12, 1, 1, 1], [0, 1, 1, 1]),         # dead rows, as the decode passes them
+        ([12, 32, 0, 25], [0, 1, 1, 1]),       # sink rows with lengths of their own
+        ([32, 5, 32], [0, 0, 1]),              # a row at full length
+        ([19], [0]),                           # one row
+        ([1], [1]),                            # one dead row: all tail
+        ([32, 32], [0, 0]),                    # no tail: every block live
+    ],
+    ids=[
+        "ragged", "dead", "sink-lengths", "full-row", "one-row",
+        "one-dead-row", "no-tail",
+    ],
+)
+def test_paged_work_list_walks_live_blocks(lens, dead):
+    """The work list holds each row's blocks through its length, rows in
+    order, its tail padded with the last item; the kernel walking it
+    matches the XLA twin on every row, the rows that point at the sink
+    too, which is what the armed attention check compares."""
+    B, KV, G, d, bs, n_blk = len(lens), 2, 2, 16, 8, 4
+    rng = np.random.default_rng(6)
+    q, kpool, vpool, _ = _paged_inputs(rng, B, KV, G, d, bs, n_blk, B * n_blk + 1)
+    tables = _sink_tables(rng, dead, n_blk, B * n_blk + 1)
+    lengths = jnp.asarray(lens, jnp.int32)
+    assert np.array_equal(np.asarray(tables[:, 0] == kvcache.SINK_BLOCK), dead)
+
+    work, n_work = paged_work_list(tables, lengths, bs)
+    want = _np_work(lens, bs, n_blk)
+    assert work.shape == (B * n_blk,) and work.dtype == jnp.int32
+    assert int(n_work) == len(want)
+    assert np.asarray(work)[: len(want)].tolist() == want
+    assert (np.asarray(work)[len(want):] == want[-1]).all()
+
+    got = decode_attention_paged(
+        q, kpool, vpool, tables, lengths, LAYER, work=(work, n_work),
+        impl="pallas", interpret=True,
+    )
+    twin = decode_attention_paged_xla(q, kpool, vpool, tables, lengths, LAYER)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(twin), rtol=2e-5, atol=2e-5
+    )
+
+
 # ---------------------------------------------------------- compile economy
 
 
@@ -382,6 +449,34 @@ def test_paged_table_contents_do_not_recompile():
             q, kpool, vpool, t, jnp.asarray([a, b], jnp.int32),
             jnp.int32(seed % N_LAYERS),
         ).block_until_ready()
+    if hasattr(fn, "_cache_size"):
+        assert fn._cache_size() == 1
+
+
+def test_paged_work_list_lengths_do_not_recompile():
+    """The grid's length is the work list's traced length: lengths and
+    sink rows that change it between calls reuse the one compiled
+    program."""
+    B, KV, G, d, bs, n_blk = 3, 1, 2, 8, 8, 4
+    rng = np.random.default_rng(7)
+    q, kpool, vpool, _ = _paged_inputs(rng, B, KV, G, d, bs, n_blk, 13)
+    tables = _sink_tables(rng, [0] * B, n_blk, 13)
+
+    def attend(q, kp, vp, t, lens, li):
+        work = paged_work_list(t, lens, bs)
+        return work[1], decode_attention_paged(
+            q, kp, vp, t, lens, li, work=work, impl="pallas", interpret=True
+        )
+
+    fn = jax.jit(attend)
+    seen = set()
+    for lens, dead_row in [((1, 2, 3), None), ((32, 1, 9), 1), ((1, 8, 30), 0)]:
+        t = tables if dead_row is None else tables.at[dead_row].set(kvcache.SINK_BLOCK)
+        n_work, out = fn(q, kpool, vpool, t, jnp.asarray(lens, jnp.int32),
+                         jnp.int32(LAYER))
+        assert np.isfinite(np.asarray(out)).all()
+        seen.add(int(n_work))
+    assert seen == {3, 7, 6}
     if hasattr(fn, "_cache_size"):
         assert fn._cache_size() == 1
 

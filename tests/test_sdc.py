@@ -455,3 +455,44 @@ def test_abft_cost_batched_matches_scalar():
             bm = max(-(-M // t_outer), 1)
             want = abft_energy_pj(abft_matmul_cost(M, N, K, bm), table)
             assert got[j] == want
+
+
+def test_checksum_engine_on_the_paged_kernel_flags_no_dead_row(smol, monkeypatch):
+    """The armed attention check recomputes sampled rows on the XLA twin,
+    dead rows too.  An evicted row, dead for more than a block of steps,
+    and a slot never used must read the same keys in the Pallas kernel
+    (interpret mode) as in the twin, so a clean run detects nothing."""
+    from repro.kernels.flash_attention import ops
+
+    paged = ops.decode_attention_paged
+
+    def pallas_paged(*args, impl=None, interpret=None, **kw):
+        if impl is None:
+            impl, interpret = "pallas", True
+        return paged(*args, impl=impl, interpret=interpret, **kw)
+
+    monkeypatch.setattr(ops, "decode_attention_paged", pallas_paged)
+    cfg, params = smol
+    eng = Engine(cfg, params, ServeConfig(
+        max_len=MAX_LEN,
+        scheduler=SchedulerConfig(batch=3, prefill_bucket=16),
+        kv=KVConfig(layout="paged", block_size=BS),
+        kernel=KernelConfig(abft="checksum"),
+    ))
+    rng = np.random.default_rng(12)
+    short, long = (
+        Request(rng.integers(0, cfg.vocab, n).astype(np.int32),
+                max_new_tokens=budget, request_id=i)
+        for i, (n, budget) in enumerate([(5, 2), (10, 3 * BS)])
+    )
+    eng.submit(short)
+    eng.submit(long)
+    steps = 0
+    while eng.step():
+        steps += 1
+    assert eng.stats["sdc_detected"] == 0 and eng.stats["quarantined"] == 0
+    assert steps > 2 * BS
+    for r in (short, long):
+        assert eng.pop_result(r.request_id).status == RequestStatus.FINISHED
+    # dead rows keep length 0: the step grows only the rows that are live
+    assert np.asarray(eng.caches["len"]).tolist() == [[0, 0, 0]] * cfg.n_layers

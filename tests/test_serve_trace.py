@@ -173,3 +173,60 @@ def test_program_names_the_trace_readers_match(smol):
     }
     for name, low in lowered.items():
         assert low.as_text().startswith(f"module @jit_{name} "), name
+
+
+def test_decode_work_matches_the_device_work_list(smol, monkeypatch):
+    """The ``work`` argument of ``serve.decode``, counted on the host from
+    the engine's rows, equals the length of the paged kernel's work list
+    that the decode program builds on the device, at every step, through
+    evictions and slots waiting on the prefill lane, whose rows point at
+    the sink and keep length 0; ``Engine.stats`` sums it beside the whole
+    grid."""
+    from repro.kernels.flash_attention import decode_attention
+    from repro.serve import engine as engine_mod
+    from repro.serve.kvcache import SINK_BLOCK
+
+    paged_work_list = decode_attention.paged_work_list
+    device, sink_lengths = [], []
+
+    def recording(tables, lengths, bs):
+        work = paged_work_list(tables, lengths, bs)
+        jax.debug.callback(lambda n: device.append(int(n)), work[1])
+        jax.debug.callback(
+            lambda t, n: sink_lengths.extend(np.asarray(n)[np.asarray(t)[:, 0] == SINK_BLOCK]),
+            tables, lengths,
+        )
+        return work
+
+    host = []
+
+    def span(name, **args):
+        if name == "serve.decode":
+            host.append(args["work"])
+        return jax.profiler.TraceAnnotation(name, **args)
+
+    monkeypatch.setattr(decode_attention, "paged_work_list", recording)
+    monkeypatch.setattr(engine_mod, "_span", span)
+    cfg, params = smol
+    slots = 4
+    eng = Engine(cfg, params, ServeConfig(
+        max_len=MAX_LEN,
+        scheduler=SchedulerConfig(batch=slots, prefill_chunk=CHUNK),
+        kv=KVConfig(layout="paged", block_size=BS),
+    ))
+    rng = np.random.default_rng(1)
+    for n, budget in [(13, 3), (20, 12), (5, 2), (30, 9), (9, 14), (17, 4)]:
+        eng.submit(Request(
+            rng.integers(0, cfg.vocab, n).astype(np.int32), max_new_tokens=budget
+        ))
+    while eng.step():
+        pass
+    jax.effects_barrier()
+
+    assert len(host) > 10
+    assert host == device
+    # a row that holds no request attends over its one sink key, step
+    # after step: its length never grows into blocks of its own
+    assert sink_lengths and set(sink_lengths) == {1}
+    assert eng.stats["decode_work_items"] == sum(host)
+    assert eng.stats["decode_grid_full"] == len(host) * slots * (MAX_LEN // BS)
