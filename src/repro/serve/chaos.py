@@ -129,11 +129,12 @@ class EpisodeReport:
 
 def check_device_tables(eng: Engine) -> None:
     """Device block tables of live rows must mirror host ownership
-    (`_PagedRow.blocks`), every entry past the reserved span aimed at the
-    sink.  A pending CoW is the one legal divergence: the device row still
-    aims at the shared tail until ``_resolve_cow`` repoints it."""
+    (`kvcache.PagedRow.blocks`), every entry past the reserved span aimed
+    at the sink.  A pending CoW is the one legal divergence: the device row
+    still aims at the shared tail until ``PagedKV.before_decode`` repoints
+    it."""
     tables = np.asarray(eng.caches["table"][0])
-    for slot, row in eng._rows.items():
+    for slot, row in eng.kv.rows.items():
         want = np.full((tables.shape[1],), kvcache.SINK_BLOCK, np.int32)
         want[: len(row.blocks)] = row.blocks
         got = tables[slot]
@@ -152,7 +153,7 @@ def audit(eng: Engine) -> None:
     accounted), device tables mirror host tables, and every request id
     sits exactly where its status says."""
     if eng.pool is not None:
-        eng.pool.assert_invariants(eng.live_block_refs())
+        eng.pool.assert_invariants(eng.kv.live_refs())
         check_device_tables(eng)
     queued = set(eng._waiting)
     active = {st.rid for st in eng._slots.values()}
@@ -605,15 +606,15 @@ def flip_kv_bit(
 
     Returns ``(victim_rid, block)`` or None when no block is eligible
     (e.g. every owned block was written this step)."""
-    refs = eng.live_block_refs()
+    refs = eng.kv.live_refs()
     cands = []
-    for slot, row in sorted(eng._rows.items()):
+    for slot, row in sorted(eng.kv.rows.items()):
         if slot not in eng._slots:
             continue  # lane/ghost rows: quarantine targets decode slots
         for b in row.blocks:
             if (
                 refs.get(b, 0) == 1
-                and b not in eng._touched
+                and b not in eng.kv.touched
                 and b != row.cow_dst
             ):
                 cands.append((slot, b))

@@ -7,9 +7,9 @@ slowest prompt and the largest ``max_new_tokens``; here the decode batch is
 a fixed ring of ``batch`` KV *slots* (one compiled decode program,
 shape-stable forever) and requests flow through it continuously:
 
-  * **admission**: a waiting request is prefilled into a batch-1 cache and
-    scattered into a free slot (`serve/kvcache.slot_store`), interleaved
-    with decode steps;
+  * **admission**: a waiting request is prefilled into a contiguous scratch
+    and published into a free slot by the KV layout
+    (`serve/kvcache.KVLayout.publish`), interleaved with decode steps;
   * **decode**: every step advances *all* occupied slots by one token;
   * **eviction + backfill**: a slot frees the moment its request finishes
     and is re-admitted from the queue on the next step — no drain barrier.
@@ -63,10 +63,16 @@ serve granularity), so both of its memory sins are fixed here:
     ``ceil(len/bk)`` KV blocks instead of scanning all ``max_len`` slots
     through a broadcast mask.  ``attention="xla"`` keeps the masked
     dense/blockwise oracle as the measured baseline.
-  * **donated KV caches**: ``_decode``/``_admit_group`` donate the cache
-    pytree, so the per-row ring scatter updates the buffers in place — no
-    per-step copy of every KV tensor (the engine always rebinds
-    ``self.caches`` to the jit output; the donated input is dead).
+  * **donated KV caches**: the decode program and the layout's publish,
+    evict and copy-on-write programs donate the cache pytree, so the
+    per-row writes update the buffers in place — no per-step copy of every
+    KV tensor (the engine always rebinds ``self.caches`` to the jit output;
+    the donated input is dead).
+
+The KV layout (contiguous rings or the paged block pool) is one object,
+``Engine.kv``, picked by ``kvcache.make_layout``: the scheduler below asks
+it to reserve, publish, release and prepare KV, and never asks which
+layout it is.
 
 Decode GEMMs can be routed through the Pallas matmul with tile sizes from
 the paper's blocking search (``core.mapper.choose_matmul_tiles``) exactly
@@ -831,33 +837,15 @@ class _SlotState:
 
 
 @dataclasses.dataclass
-class _PagedRow:
-    """Block ownership of one live paged request (host side)."""
-
-    blocks: list[int]            # logical block -> physical, len == total
-    plen: int                    # prompt tokens
-    n_shared_full: int           # leading full blocks aliased via the index
-    tail_shared: bool            # partial prompt tail aliased (CoW pending)
-    cow_dst: int | None          # pre-allocated CoW target for the tail
-
-    def hit_tokens(self, block_size: int) -> int:
-        """Prompt tokens aliased through the prefix index (at commit,
-        before copy-on-write privatises the tail)."""
-        tail = self.plen % block_size if self.tail_shared else 0
-        return self.n_shared_full * block_size + tail
-
-
-@dataclasses.dataclass
 class _PrefillLane:
     """One mid-flight chunked prefill: the PREFILLING request holds a slot
-    (and, paged, its blocks) while its prompt streams through the batch-1
+    and its KV reservation while its prompt streams through the batch-1
     scratch cache chunk by chunk.  Nothing is published to the shared KV
     until install time, so dropping a lane needs no device writes."""
 
     rid: int
     slot: int
     filled: int = 0              # prompt tokens already through the scratch
-    row: _PagedRow | None = None  # paged ownership (radix-registered at install)
 
 
 def _pallas_mm(x: jax.Array, w: jax.Array) -> jax.Array:
@@ -918,24 +906,6 @@ class Engine:
         else:
             self._impl = None
         self._attn = "flash" if scfg.attention == "flash" else None
-        self._paged = scfg.kv_layout == "paged"
-
-        if self._paged:
-            if not kvcache.supports_paged(cfg):
-                raise ValueError(
-                    f"kv_layout='paged' needs all-global attention; "
-                    f"{cfg.name} has ring/recurrent/hybrid caches"
-                )
-            nb = scfg.resolved_num_blocks()
-            self.caches = kvcache.build_paged_caches(
-                cfg, scfg.batch, scfg.max_len, nb, scfg.block_size
-            )
-            self.pool = kvcache.BlockPool(nb, scfg.block_size)
-            self._axes = None
-        else:
-            self.caches = kvcache.build_caches(cfg, scfg.batch, scfg.max_len)
-            self.pool = None
-            self._axes = kvcache.slot_axes(cfg, scfg.max_len)
         self._free: deque[int] = deque(range(scfg.batch))
         # waiting rids, kept sorted by (-priority, seq): head = best request.
         # Preempted requests keep their original seq, so they re-enter ahead
@@ -943,7 +913,6 @@ class Engine:
         self._waiting: list[int] = []
         self._reqs: dict[int, _ReqInfo] = {}
         self._slots: dict[int, _SlotState] = {}
-        self._rows: dict[int, _PagedRow] = {}
         self._outputs: dict[int, list[int]] = {}
         self._next_rid = 0
         self._next_seq = 0
@@ -967,13 +936,15 @@ class Engine:
             "snapshots": 0,     # recovery snapshots staged
             "sdc_detected": 0,  # abft: steps whose checksums flagged
             "sdc_retried": 0,   # abft: oracle-substrate step re-executions
-            "prompt_tokens": 0,      # paged: prompt tokens of committed admissions
+            "prompt_tokens": 0,      # paged: prompt tokens of reserved admissions
             "prefix_hit_tokens": 0,  # ... of them aliased through the prefix index
             "decode_work_items": 0,  # paged: the decode kernel's items per layer
             "decode_grid_full": 0,   # ... and its whole (slots x blocks) grid
         }
+        self.kv = kvcache.make_layout(cfg, scfg, self.stats)
+        self.caches = self.kv.init_caches()
 
-        model, impl, axes = self.model, self._impl, self._axes
+        model, impl = self.model, self._impl
         max_len = scfg.max_len
         sample_one = self._sampler()
 
@@ -984,44 +955,24 @@ class Engine:
                 lambda lg, k: sample_one(lg, jax.random.fold_in(k, jnp.int32(0)))
             )(logits, keys)
 
-        def admit_fn(params, toks, big, slots_, keys, true_lens):
-            """Fused admission: prefill `n` prompts (right-padded rows mask
-            their tail; exact rows mask nothing), scatter each into its
-            slot, and sample each request's first token — one dispatch."""
+        def prefill_fn(params, toks, keys, true_lens):
+            """Admission, phase 1: prefill `n` prompts (right-padded rows
+            are masked at publication) into a contiguous scratch and sample
+            each request's first token.  Phase 2 publishes each row through
+            the layout (`kvcache.KVLayout.publish`), in both layouts."""
             n = toks.shape[0]
             small = kvcache.build_caches(cfg, n, max_len)
             with L.matmul_override(impl):
                 logits, small = model.prefill(
                     params, toks, small, last_index=true_lens - 1
                 )
-            small = kvcache.mask_prompt_tail(small, true_lens)
-            for i in range(n):
-                big = kvcache.slot_store(
-                    big, kvcache.take_slot(small, i, axes), slots_[i], axes
-                )
-            return first_tok(logits, keys), big
+            return first_tok(logits, keys), small
 
-        def paged_prefill_fn(params, toks, keys, true_lens):
-            """Paged admission, phase 1: prefill into a contiguous scratch
-            (the SAME program shape the contiguous oracle admits through,
-            so first tokens and packed K/V stay bitwise comparable) and
-            sample each request's first token.  Phase 2 packs the scratch
-            into pool blocks row by row (`kvcache.paged_store_row_blocks`),
-            skipping blocks aliased from the prefix index."""
-            n = toks.shape[0]
-            small = kvcache.build_caches(cfg, n, max_len)
-            with L.matmul_override(impl):
-                logits, small = model.prefill(
-                    params, toks, small, last_index=true_lens - 1
-                )
-            return first_tok(logits, keys), {"k": small["k"], "v": small["v"]}
-
-        # the KV cache pytree is DONATED: the ring scatter and admission
-        # slot_store update the buffers in place instead of copying every
-        # KV tensor per step.  The engine immediately rebinds self.caches
-        # to the jit output, so the consumed input is never read again.
-        # The paged helpers follow the same contract: pack/set/CoW are
-        # donated scatters into the pool, never pool copies.
+        # the KV cache pytree is DONATED through decode and every layout
+        # program: the writes update the buffers in place instead of
+        # copying every KV tensor per step.  The engine immediately rebinds
+        # self.caches to the jit output, so the consumed input is never
+        # read again.
         # ---- abft state (kernels/abft.py) ----
         # fault operand: one-shot transient-SDC injection point threaded
         # through the jitted decode program (zeros = disarmed; the armed
@@ -1045,15 +996,7 @@ class Engine:
             self._colstats = jax.jit(weight_colstats)(params)
         self._decode = self._make_decode(self._attn)
         self._fallback_done = False
-        self._admit_group = jax.jit(admit_fn, donate_argnums=(2,))
-        self._paged_prefill = jax.jit(paged_prefill_fn)
-        self._pack_row = jax.jit(kvcache.paged_store_row_blocks, donate_argnums=(0,))
-        self._set_row = jax.jit(kvcache.paged_set_row, donate_argnums=(0,))
-        self._cow = jax.jit(kvcache.paged_copy_block, donate_argnums=(0,))
-        if self._paged:
-            self._sink_row = np.zeros((scfg.max_len // scfg.block_size,), np.int32)
-        else:
-            self._sink_row = None
+        self._prefill = jax.jit(prefill_fn)
 
         # ---- token-level unified scheduler (prefill_chunk > 0) ----
         # Prompts stream through a persistent batch-1 contiguous scratch
@@ -1061,9 +1004,9 @@ class Engine:
         # the scratch's length cursor (`positions=None` in logits_fn), so
         # chunk N continues exactly where chunk N-1 stopped and the K/V/
         # logits bits match a monolithic prefill of the whole prompt.
-        # Install reuses the monolithic publication paths verbatim
-        # (mask_prompt_tail + slot_store, or paged set-row + pack), which
-        # is what makes the prefill_chunk=0 engine a bitwise oracle.
+        # Install publishes through the same layout call as monolithic
+        # admission, which is what makes the prefill_chunk=0 engine a
+        # bitwise oracle.
         self._chunk = scfg.prefill_chunk
         self._lane: _PrefillLane | None = None
         self._scratch = None
@@ -1092,46 +1035,10 @@ class Engine:
                 )[:, 0]
                 return first_tok(sel, key[None]), scratch
 
-            def install_fn(big, scratch, slot, true_lens):
-                """Publish a completed lane into the contiguous ring — the
-                exact monolithic admission path (tail mask + slot scatter),
-                so the installed slot is bitwise the monolithic one."""
-                small = kvcache.mask_prompt_tail(scratch, true_lens)
-                return kvcache.slot_store(
-                    big, kvcache.take_slot(small, 0, axes), slot, axes
-                )
-
             self._chunk_step = jax.jit(chunk_fn, donate_argnums=(2,))
-            self._install_slot = jax.jit(install_fn, donate_argnums=(0,))
             self._fresh_scratch = jax.jit(
                 lambda: kvcache.build_caches(cfg, 1, max_len)
             )
-
-        # optional per-physical-block checksum audit (paged only): host
-        # mirror of |kpool|+|vpool| sums per block, verified after every
-        # step against the blocks legally written that step
-        self._kv_sums: np.ndarray | None = None
-        self._pool_sums = None
-        self._touched: set[int] = set()
-        # abft localizes inter-step KV flips through the same per-block
-        # fingerprints, so it arms them even without kv_checksum
-        if scfg.kv_checksum or (self._abft and self._paged):
-
-            def pool_sums_fn(caches):
-                # per block (axis 1 of the packed pools): every layer,
-                # row and lane
-                k = jnp.sum(
-                    jnp.abs(caches["kpool"].astype(jnp.float32)),
-                    axis=(0, 2, 3),
-                )
-                v = jnp.sum(
-                    jnp.abs(caches["vpool"].astype(jnp.float32)),
-                    axis=(0, 2, 3),
-                )
-                return k + v
-
-            self._pool_sums = jax.jit(pool_sums_fn)
-            self._refresh_kv_sums()
 
         # crash consistency: journal + periodic snapshots (serve/recovery)
         self.recovery = None
@@ -1258,11 +1165,10 @@ class Engine:
             self.stats["fallbacks"] += 1
             return self._decode(*args)
 
-    def _refresh_kv_sums(self) -> None:
-        """(Re)baseline the per-block checksum mirror from the current
-        device pools — at init and after a snapshot restore."""
-        if self._pool_sums is not None:
-            self._kv_sums = np.asarray(self._pool_sums(self.caches))
+    @property
+    def pool(self) -> kvcache.BlockPool | None:
+        """The paged layout's block pool; None when contiguous."""
+        return self.kv.pool
 
     # ---------------------------------------------------------- admission --
     def submit(self, req: Request) -> int:
@@ -1289,21 +1195,7 @@ class Engine:
         if len(prompt) >= max_len:
             prompt = prompt[-(max_len - 1) :]
         budget = min(int(req.max_new_tokens), max_len - len(prompt))
-        if self._paged:
-            # never let one request outgrow the whole pool: its admission
-            # would wait forever for blocks that can't exist (deadlock),
-            # and silently shrinking the budget would quietly diverge from
-            # the contiguous oracle — reject loudly instead.  With the
-            # default pool sizing (batch * max_len tokens) this can never
-            # trigger: the max_len truncation above already bounds
-            # prompt + budget to max_len <= capacity.
-            cap_tokens = (self.pool.num_blocks - 1) * self.scfg.block_size
-            if len(prompt) + budget > cap_tokens:
-                raise ValueError(
-                    f"request {rid} needs {len(prompt) + budget} KV tokens "
-                    f"but the whole pool holds {cap_tokens}; grow "
-                    f"num_blocks or shorten the request"
-                )
+        self.kv.check_request(rid, len(prompt) + budget)
         deadline = (
             self._step_no + req.deadline_steps
             if req.deadline_steps is not None
@@ -1429,186 +1321,77 @@ class Engine:
             keys[j], tlens[j] = info.key, len(info.prompt)
         return toks, keys, tlens
 
+    def _claim(self, info: _ReqInfo) -> tuple[int, dict] | None:
+        """Take the queue head ``info`` off the queue with a free slot and
+        its KV reservation.  Returns the slot and the reservation's span
+        metadata, or None, taking nothing, when the layout cannot take the
+        request now (a block-starved pool): the head of the line waits."""
+        slot = self._free[0]
+        meta = self.kv.reserve(slot, info.prompt, info.budget)
+        if meta is None:
+            return None
+        self._waiting.pop(0)
+        self._free.popleft()
+        return slot, meta
+
     def _admit_waiting(self, on_token: TokenCallback | None) -> int:
-        """Backfill every free slot from the queue.  Admissions sharing a
-        prefill length run as ONE fused jitted call (prefill + tail mask +
-        slot scatter + first-token sample); right-padding to
+        """Backfill free slots from the queue, in strict (-priority,
+        arrival) order.  Admissions sharing a prefill length run as ONE
+        prefill into a contiguous scratch; right-padding to
         ``prefill_bucket`` collapses mixed prompt lengths onto one compiled
         shape where that is exact (`kvcache.supports_padded_prefill`).
-        Returns how many requests were admitted."""
-        if self._paged:
-            return self._admit_waiting_paged(on_token)
+        Each row is then published into its slot.  Returns how many
+        requests were admitted."""
         groups: dict[int, list[tuple[_ReqInfo, int]]] = {}
         while self._free and self._waiting:
-            info = self._reqs[self._waiting.pop(0)]
-            slot = self._free.popleft()
+            info = self._reqs[self._waiting[0]]
+            claimed = self._claim(info)
+            if claimed is None:
+                break
+            slot = claimed[0]
+            # published in this same step, so the prompt can be offered to
+            # the prefix index at once
+            self.kv.register(slot, info.prompt)
             lpad = self._bucket_len(len(info.prompt))
             groups.setdefault(lpad, []).append((info, slot))
 
         for lpad, items in groups.items():
             toks, keys, tlens = self._prompt_batch(lpad, [it[0] for it in items])
-            slots_ = np.asarray([it[1] for it in items], np.int32)
-            toks0, self.caches = self._admit_group(
+            toks0, scratch = self._prefill(
                 self.params,
                 jnp.asarray(toks),
-                self.caches,
-                jnp.asarray(slots_),
                 jnp.asarray(keys),
                 jnp.asarray(tlens),
             )
             toks0 = np.asarray(toks0)
             self.stats["admitted"] += len(items)
             for j, (info, slot) in enumerate(items):
-                self._activate(info, slot, int(toks0[j]), on_token)
-        self.stats["peak_active"] = max(self.stats["peak_active"], len(self._slots))
-        return sum(len(items) for items in groups.values())
-
-    # ------------------------------------------------------ paged admission --
-    def _admit_waiting_paged(self, on_token: TokenCallback | None) -> int:
-        """Paged admission: a request enters when a slot AND enough free
-        blocks are available (strict order over (-priority, arrival) — the
-        queue head never gets jumped).  Ownership is committed host-side
-        first (prefix match -> retain aliases, allocate the rest, register
-        this chain), then each prefill group runs as one jitted call and
-        each row's private blocks are packed into the pool."""
-        scfg = self.scfg
-        bs = scfg.block_size
-        n_blk = scfg.max_len // bs
-        groups: dict[int, list[tuple[_ReqInfo, int, _PagedRow]]] = {}
-        while self._free and self._waiting:
-            info = self._reqs[self._waiting[0]]
-            row = self._commit_row(info)
-            if row is None:
-                break  # head-of-line waits for completions to free blocks
-            self._waiting.pop(0)
-            slot = self._free.popleft()
-            # monolithic admission packs in this same step, so the chain
-            # can be published to the prefix index immediately
-            self._register_chain(info, row)
-            self._rows[slot] = row
-            if self._kv_sums is not None:
-                # checksum mode: admission packs (or aliases) these blocks
-                # this step; aliased prefix blocks are untouched on device
-                # but marking them is a harmless over-approximation
-                self._touched.update(row.blocks)
-            lpad = self._bucket_len(row.plen)
-            groups.setdefault(lpad, []).append((info, slot, row))
-
-        for lpad, items in groups.items():
-            toks, keys, tlens = self._prompt_batch(lpad, [it[0] for it in items])
-            toks0, scratch = self._paged_prefill(
-                self.params,
-                jnp.asarray(toks),
-                jnp.asarray(keys),
-                jnp.asarray(tlens),
-            )
-            toks0 = np.asarray(toks0)
-            self.stats["admitted"] += len(items)
-            for j, (info, slot, row) in enumerate(items):
-                table_row = np.full((n_blk,), kvcache.SINK_BLOCK, np.int32)
-                table_row[: len(row.blocks)] = row.blocks
-                self.caches = self._set_row(
-                    self.caches,
-                    jnp.int32(slot),
-                    jnp.asarray(table_row),
-                    jnp.int32(row.plen),
+                self.caches, _ = self.kv.publish(
+                    self.caches, slot, scratch, j, len(info.prompt)
                 )
-                n_prompt = -(-row.plen // bs)
-                start = row.n_shared_full
-                n_pack = n_prompt - start - (1 if row.tail_shared else 0)
-                if n_pack > 0:
-                    self.caches = self._pack_row(
-                        self.caches,
-                        scratch,
-                        jnp.int32(j),
-                        jnp.int32(start),
-                        jnp.asarray(row.blocks[start : start + n_pack], jnp.int32),
-                    )
+            for j, (info, slot) in enumerate(items):
                 self._activate(info, slot, int(toks0[j]), on_token)
         self.stats["peak_active"] = max(self.stats["peak_active"], len(self._slots))
         return sum(len(items) for items in groups.values())
-
-    def _commit_row(self, info: _ReqInfo) -> _PagedRow | None:
-        """Host-side block ownership for one paged admission: retain prefix
-        aliases, allocate the rest, reserve the CoW target (so the first
-        divergent write can never be starved by admissions racing it to
-        the free list).  Returns None when the pool cannot satisfy the
-        request right now — nothing is committed in that case."""
-        scfg = self.scfg
-        bs = scfg.block_size
-        prompt, budget = info.prompt, info.budget
-        plen = len(prompt)
-        total = -(-(plen + budget) // bs)
-        shared_full: list[int] = []
-        shared_tail = None
-        if scfg.prefix_sharing:
-            shared_full, shared_tail = self.pool.match_prefix(prompt.tolist())
-        n_shared = len(shared_full) + (1 if shared_tail is not None else 0)
-        cow_needed = shared_tail is not None and budget > 1
-        need = total - n_shared + (1 if cow_needed else 0)
-        if need > self.pool.free_blocks:
-            return None
-        for b in shared_full:
-            self.pool.retain(b)
-        if shared_tail is not None:
-            self.pool.retain(shared_tail)
-        blocks = list(shared_full)
-        if shared_tail is not None:
-            blocks.append(shared_tail)
-        while len(blocks) < total:
-            blocks.append(self.pool.alloc())
-        cow_dst = self.pool.alloc() if cow_needed else None
-        row = _PagedRow(
-            blocks=blocks,
-            plen=plen,
-            n_shared_full=len(shared_full),
-            tail_shared=shared_tail is not None,
-            cow_dst=cow_dst,
-        )
-        self.stats["prompt_tokens"] += plen
-        self.stats["prefix_hit_tokens"] += row.hit_tokens(bs)
-        return row
-
-    def _register_chain(self, info: _ReqInfo, row: _PagedRow) -> None:
-        """Publish this row's prompt blocks in the radix prefix index.
-        Monolithic admission does this at commit time (it packs within the
-        same step); chunked admission defers it to install time — a block
-        whose K/V has not been packed yet must never be aliased by a
-        concurrent admission."""
-        if not self.scfg.prefix_sharing:
-            return
-        bs = self.scfg.block_size
-        toks = info.prompt.tolist()
-        n_full = row.plen // bs
-        prev = -1
-        for i in range(n_full):
-            self.pool.register(prev, tuple(toks[i * bs : (i + 1) * bs]), row.blocks[i])
-            prev = row.blocks[i]
-        tail = tuple(toks[n_full * bs :])
-        if tail and n_full < len(row.blocks):
-            self.pool.register(prev, tail, row.blocks[n_full])
 
     # ----------------------------------------------- chunked prefill lane --
     def _start_lane(self) -> bool:
-        """Claim the queue head for the scratch lane: reserve a slot (and,
-        paged, commit block ownership) and mark it PREFILLING.  Returns
-        False when no request can start (empty queue, no free slot, or a
-        block-starved pool)."""
+        """Claim the queue head for the scratch lane: a slot and its KV
+        reservation, and mark it PREFILLING.  Returns False when no request
+        can start (empty queue, no free slot, or a block-starved pool)."""
         if self._lane is not None or not self._waiting or not self._free:
             return False
         info = self._reqs[self._waiting[0]]
         with _span("serve.lane.start", rid=info.rid) as span:
-            row = None
-            if self._paged:
-                row = self._commit_row(info)
-                if row is None:
-                    return False
-                span.set_metadata(hit_tokens=row.hit_tokens(self.scfg.block_size))
-            self._waiting.pop(0)
-            slot = self._free.popleft()
+            claimed = self._claim(info)
+            if claimed is None:
+                return False
+            slot, meta = claimed
+            if meta:
+                span.set_metadata(**meta)
             info.status = RequestStatus.PREFILLING
             self._scratch = self._fresh_scratch()
-            self._lane = _PrefillLane(rid=info.rid, slot=slot, row=row)
+            self._lane = _PrefillLane(rid=info.rid, slot=slot)
         return True
 
     def _advance_lane(self):
@@ -1637,69 +1420,32 @@ class Engine:
         return end >= plen, tok0
 
     def _install_lane(self, tok0, on_token: TokenCallback | None) -> None:
-        """Publish a completed lane: install the scratch K/V through the
-        EXACT monolithic publication path (contiguous tail-mask + slot
-        scatter, or paged set-row + block pack), register the paged chain
-        in the prefix index, and activate the request with its sampled
-        first token — from here on it is indistinguishable from a
-        monolithically admitted request."""
+        """Publish a completed lane through the same layout call as
+        monolithic admission, offer its prompt to the prefix index, and
+        activate the request with its sampled first token — from here on it
+        is indistinguishable from a monolithically admitted request."""
         lane = self._lane
         self._lane = None
         with _span("serve.lane.install", rid=lane.rid) as span:
             info = self._reqs[lane.rid]
-            plen = len(info.prompt)
-            slot = lane.slot
-            if self._paged:
-                row = lane.row
-                bs = self.scfg.block_size
-                n_blk = self.scfg.max_len // bs
-                table_row = np.full((n_blk,), kvcache.SINK_BLOCK, np.int32)
-                table_row[: len(row.blocks)] = row.blocks
-                self.caches = self._set_row(
-                    self.caches,
-                    jnp.int32(slot),
-                    jnp.asarray(table_row),
-                    jnp.int32(plen),
-                )
-                n_prompt = -(-plen // bs)
-                start = row.n_shared_full
-                n_pack = n_prompt - start - (1 if row.tail_shared else 0)
-                span.set_metadata(packed=max(n_pack, 0))
-                if n_pack > 0:
-                    self.caches = self._pack_row(
-                        self.caches,
-                        {"k": self._scratch["k"], "v": self._scratch["v"]},
-                        jnp.int32(0),
-                        jnp.int32(start),
-                        jnp.asarray(row.blocks[start : start + n_pack], jnp.int32),
-                    )
-                self._register_chain(info, row)
-                self._rows[slot] = row
-                if self._kv_sums is not None:
-                    self._touched.update(row.blocks)
-            else:
-                self.caches = self._install_slot(
-                    self.caches,
-                    self._scratch,
-                    jnp.int32(slot),
-                    jnp.asarray([plen], jnp.int32),
-                )
+            self.kv.register(lane.slot, info.prompt)
+            self.caches, meta = self.kv.publish(
+                self.caches, lane.slot, self._scratch, 0, len(info.prompt)
+            )
+            if meta:
+                span.set_metadata(**meta)
             self.stats["admitted"] += 1
-            self._activate(info, slot, int(np.asarray(tok0)[0]), on_token)
+            self._activate(info, lane.slot, int(np.asarray(tok0)[0]), on_token)
             self.stats["peak_active"] = max(self.stats["peak_active"], len(self._slots))
 
     def _drop_lane(self) -> None:
         """Release a mid-flight lane's resources.  No device writes are
         needed: install is the only publisher, so the device block table
-        and slot caches were never touched — the slot and any committed
-        blocks simply return to their free pools."""
+        and slot caches were never touched — the slot and its reservation
+        simply return to their free pools."""
         lane = self._lane
         self._lane = None
-        if lane.row is not None:
-            for b in lane.row.blocks:
-                self.pool.release(b)
-            if lane.row.cow_dst is not None:
-                self.pool.release(lane.row.cow_dst)
+        self.kv.drop(lane.slot)
         self._free.append(lane.slot)
 
     def _preempt_lane(self) -> None:
@@ -1744,79 +1490,16 @@ class Engine:
                 self._install_lane(tok0, on_token)
         return progressed
 
-    def _resolve_cow(self) -> None:
-        """Before rows write: give every slot still aliasing a shared
-        prompt-tail block its pre-reserved private copy (first divergent
-        write is about to land at ``plen``, inside that block)."""
-        pending = [
-            (slot, self._rows[slot])
-            for slot in sorted(self._slots)
-            if slot in self._rows and self._rows[slot].cow_dst is not None
-        ]
-        if not pending:
-            return
-        with _span("serve.cow", n=len(pending)):
-            for slot, row in pending:
-                lb = row.plen // self.scfg.block_size
-                src = row.blocks[lb]
-                self.caches = self._cow(
-                    self.caches,
-                    jnp.int32(slot),
-                    jnp.int32(lb),
-                    jnp.int32(src),
-                    jnp.int32(row.cow_dst),
-                )
-                self.pool.release(src)
-                if self._kv_sums is not None:
-                    self._touched.add(row.cow_dst)
-                row.blocks[lb] = row.cow_dst
-                row.cow_dst = None
-                row.tail_shared = False
-
-    def _evict_paged(self, slot: int) -> None:
-        """Release a finished/cancelled/preempted row: repoint its device
-        table at the sink (the always-full-batch decode keeps writing
-        through dead rows, and these blocks are about to be reused) and
-        return every owned block — including a still-pending CoW
-        reservation — to the pool."""
-        row = self._rows.pop(slot)
-        self.caches = self._set_row(
-            self.caches,
-            jnp.int32(slot),
-            jnp.asarray(self._sink_row),
-            jnp.int32(0),
-        )
-        for b in row.blocks:
-            self.pool.release(b)
-        if row.cow_dst is not None:
-            self.pool.release(row.cow_dst)
-
     def _release_slot(self, slot: int) -> None:
         """Evict a live slot for any reason (finish, cancel, deadline,
-        preemption): paged rows release their blocks through the sink
-        repoint, and the slot returns to the free ring for backfill."""
+        preemption): the layout releases its KV, and the slot returns to
+        the free ring for backfill."""
         del self._slots[slot]
-        if self._paged:
-            self._evict_paged(slot)
+        self.caches = self.kv.release(self.caches, slot)
         self._free.append(slot)
 
     def _slot_of(self, rid: int) -> int:
         return next(s for s, st in self._slots.items() if st.rid == rid)
-
-    def live_block_refs(self) -> dict[int, int]:
-        """Physical block -> reference count implied by live rows (the
-        ground truth the pool's refcounts must mirror; used by the fuzz
-        suite's invariant checks)."""
-        refs: dict[int, int] = {}
-        rows = list(self._rows.values())
-        if self._lane is not None and self._lane.row is not None:
-            rows.append(self._lane.row)  # lane ownership commits at start
-        for row in rows:
-            for b in row.blocks:
-                refs[b] = refs.get(b, 0) + 1
-            if row.cow_dst is not None:
-                refs[row.cow_dst] = refs.get(row.cow_dst, 0) + 1
-        return refs
 
     # ---------------------------------------------------------- lifecycle --
     def status(self, rid: int) -> RequestStatus:
@@ -1902,19 +1585,6 @@ class Engine:
             self.stats["expired"] += 1
             self._finish(info, RequestStatus.FAILED, "deadline expired while active")
 
-    def _blocks_needed(self, info: _ReqInfo) -> int:
-        """Free blocks the paged admission of ``info`` would consume right
-        now (worst-case reservation minus prefix aliases, plus a CoW
-        target) — the same arithmetic `_admit_waiting_paged` commits."""
-        bs = self.scfg.block_size
-        total = -(-(len(info.prompt) + info.budget) // bs)
-        if not self.scfg.prefix_sharing:
-            return total
-        shared_full, shared_tail = self.pool.match_prefix(info.prompt.tolist())
-        n_shared = len(shared_full) + (1 if shared_tail is not None else 0)
-        cow = shared_tail is not None and info.budget > 1
-        return total - n_shared + (1 if cow else 0)
-
     def _preempt_pass(self) -> None:
         """Priority preemption: while the best waiting request is starved
         (no free slot, or — paged — not enough free blocks) and a strictly
@@ -1924,9 +1594,7 @@ class Engine:
         blocks stay referenced) costs replay latency, never correctness."""
         while self._waiting:
             head = self._reqs[self._waiting[0]]
-            starved = not self._free or (
-                self._paged and self._blocks_needed(head) > self.pool.free_blocks
-            )
+            starved = not self._free or not self.kv.fits(head.prompt, head.budget)
             if not starved:
                 return
             victims = sorted(
@@ -1949,26 +1617,11 @@ class Engine:
         self.stats["quarantined"] += 1
         self._finish(info, RequestStatus.FAILED, reason)
 
-    def _audit_kv_checksums(self) -> None:
-        """kv_checksum mode: recompute per-physical-block sums and compare
-        against last step's mirror.  A block that changed without a legal
-        write this step (``self._touched``) is corrupt: every request
-        referencing it is quarantined.  NaN sums compare equal to
-        themselves here, so an already-quarantined poisoned block does not
-        re-fire once it sits idle in the free list."""
-        sums = np.asarray(self._pool_sums(self.caches))
-        prev = self._kv_sums
-        changed = (sums != prev) & ~(np.isnan(sums) & np.isnan(prev))
-        if self._touched:
-            changed[list(self._touched)] = False
+    def _audit_kv(self) -> None:
+        """Quarantine every live request that owns a KV block the layout's
+        audit found changed without a legal write (kv_checksum/abft)."""
         prefix = "sdc: " if self._abft else ""
-        for b in np.nonzero(changed)[0]:
-            b = int(b)
-            owners = [
-                s
-                for s, row in self._rows.items()
-                if b in row.blocks or row.cow_dst == b
-            ]
+        for b, owners in self.kv.audit(self.caches):
             for s in owners:
                 if s in self._slots:
                     self._quarantine(
@@ -1976,7 +1629,6 @@ class Engine:
                         f"{prefix}KV corruption: block {b} checksum "
                         f"changed without a write",
                     )
-        self._kv_sums = sums
 
     def arm_fault(
         self,
@@ -2077,15 +1729,15 @@ class Engine:
 
     def _step_core(self, on_token: TokenCallback | None) -> bool:
         with _span("serve.schedule"):
-            if self._abft and self._kv_sums is not None:
+            if self._abft:
                 # audit BEFORE decode, against the blocks the PREVIOUS step
                 # legally wrote: an inter-step KV flip quarantines its owner
                 # before the poisoned attention read, so the victim's partial
                 # output stays a clean oracle prefix and survivors never see
                 # the corrupt block
-                self._audit_kv_checksums()
+                self._audit_kv()
             self._step_no += 1
-            self._touched = {kvcache.SINK_BLOCK}
+            self.kv.begin_step()
             self._expire_deadlines()
             self._preempt_pass()
             admitted = False
@@ -2097,10 +1749,8 @@ class Engine:
                         n = self._admit_waiting(on_token)
                         span.set_metadata(n=n)
                     if not n:
-                        break  # paged: head of queue waits for free blocks
+                        break  # the head of the queue waits for free blocks
                     admitted = True
-            if self._paged:
-                self._resolve_cow()
             if not self._slots:
                 if self._lane is not None:
                     # a mid-flight prefill lane IS progress: decode has nothing
@@ -2131,29 +1781,16 @@ class Engine:
                         self._stalled = 0
                 return bool(self._waiting)
             self._stalled = 0
+            self.caches = self.kv.before_decode(self.caches, self._slots)
 
             B = self.scfg.batch
             keys = np.zeros((B, 2), np.uint32)
             ts = np.zeros((B,), np.int32)
             for s, st in self._slots.items():
                 keys[s], ts[s] = self._reqs[st.rid].key, st.emitted
-            if self._kv_sums is not None:
-                # the one block each live row legally appends to this step:
-                # decode writes KV at position plen + emitted - 1 (the first
-                # generated token's KV lands on the next step's feed)
-                bs = self.scfg.block_size
-                for s, st in self._slots.items():
-                    row = self._rows[s]
-                    self._touched.add(row.blocks[(row.plen + st.emitted - 1) // bs])
             toks = jnp.asarray(self._cur_tok[:, None])
             jkeys, jts = jnp.asarray(keys), jnp.asarray(ts)
-            args = {"rows": len(self._slots)}
-            if self._paged:
-                args["work"] = self._decode_work()
-                self.stats["decode_work_items"] += args["work"]
-                self.stats["decode_grid_full"] += (
-                    B * self.scfg.max_len // self.scfg.block_size
-                )
+            args = {"rows": len(self._slots), **self.kv.decode_work(self._slots)}
         with _span("serve.decode", **args):
             if self._abft:
                 fault = self._fault.copy()
@@ -2218,23 +1855,10 @@ class Engine:
                     continue  # the done-callback already cancelled it
                 self._release_slot(s)  # backfilled at the next step
                 self._finish(self._reqs[rid], RequestStatus.FINISHED, "")
-            if self._kv_sums is not None and not self._abft:
-                self._audit_kv_checksums()
+            if not self._abft:
+                self._audit_kv()
             span.set_metadata(finished=len(finished))
         return True
-
-    def _decode_work(self) -> int:
-        """The paged decode kernel's work items per layer this step, from
-        the host's own rows (``decode_attention.paged_work_list`` counts
-        the same on the device from the lengths): one per block a live row
-        reads through its newest token, at position ``plen + emitted - 1``,
-        and one for each row that holds no request, whose length is 0."""
-        bs = self.scfg.block_size
-        live = sum(
-            -(-(self._rows[s].plen + st.emitted) // bs)
-            for s, st in self._slots.items()
-        )
-        return self.scfg.batch - len(self._slots) + live
 
     def pop_result(self, rid: int) -> RequestResult:
         """Take a request's :class:`RequestResult`.  Terminal requests are

@@ -26,8 +26,9 @@ garbage that nothing reads, and the next admission overwrites it.
 
 The PAGED layout (``build_paged_caches`` + :class:`BlockPool` + the
 ``paged_*`` device ops, below) replaces the contiguous per-slot rings with
-a refcounted block pool, per-row block tables and a radix prefix index —
-see the "paged layout" section further down for the full contract.
+a refcounted block pool, per-row block tables and a radix prefix index.
+The engine reaches either layout only through :class:`KVLayout`
+(:class:`ContiguousKV`, :class:`PagedKV`; see "layouts" at the end).
 
 Donation contract: the engine donates this whole pytree through its jitted
 decode/admission programs, so every per-step mutation must be expressible
@@ -42,11 +43,14 @@ these writes maintain — live entries of every cache occupy exactly slots
 
 from __future__ import annotations
 
+import collections
+import dataclasses
 import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.arch import layers as L
 from repro.arch import rglru as G
@@ -607,4 +611,399 @@ def supports_padded_prefill(cfg: ModelConfig) -> bool:
         and cfg.mixer == "attention"
         and cfg.sliding_window is None
         and cfg.moe is None
+    )
+
+
+# ----------------------------------------------------------------- layouts --
+
+
+@dataclasses.dataclass
+class PagedRow:
+    """Block ownership of one paged request (host side)."""
+
+    blocks: list[int]            # logical block -> physical, len == total
+    plen: int                    # prompt tokens
+    n_shared_full: int           # leading full blocks aliased via the index
+    tail_shared: bool            # partial prompt tail aliased (CoW pending)
+    cow_dst: int | None          # pre-allocated CoW target for the tail
+
+    def hit_tokens(self, block_size: int) -> int:
+        """Prompt tokens aliased through the prefix index (at reserve,
+        before copy-on-write privatises the tail)."""
+        tail = self.plen % block_size if self.tail_shared else 0
+        return self.n_shared_full * block_size + tail
+
+    def owned(self) -> list[int]:
+        """Every block this row holds a reference to."""
+        return self.blocks + ([] if self.cow_dst is None else [self.cow_dst])
+
+
+def pool_sums(caches: Any) -> jax.Array:
+    """Per-block ``|K| + |V|`` sums over every layer, row and lane."""
+    total = lambda p: jnp.sum(jnp.abs(p.astype(jnp.float32)), axis=(0, 2, 3))
+    return total(caches["kpool"]) + total(caches["vpool"])
+
+
+class KVLayout:
+    """The engine's one KV-layout seam, picked by :func:`make_layout`: the
+    engine asks it every question whose answer depends on the layout.  The
+    engine owns the device pytree (``Engine.caches``); each method that
+    writes it takes ``caches`` and returns the new pytree, through programs
+    that donate it.  A slot's KV is *reserved* at admission, *published*
+    once its prompt is prefilled into a contiguous scratch, and *released*
+    at eviction, or *dropped* if never published (a prefill lane cancelled
+    or preempted mid-prompt, which wrote nothing to the device).
+
+    ``stats`` is ``Engine.stats``; a layout adds its own counters to it.
+    ``rows`` maps each published slot to its ownership record.  The
+    defaults are a layout's without a block pool: nothing to reserve, copy
+    on write, count or audit."""
+
+    pool: BlockPool | None = None
+
+    def __init__(self, cfg: ModelConfig, scfg: Any, stats: dict):
+        self.cfg = cfg
+        self.batch = scfg.scheduler.batch
+        self.max_len = scfg.max_len
+        self.stats = stats
+        self.rows: dict[int, PagedRow] = {}
+
+    def check_request(self, rid: int, n_tokens: int) -> None:
+        """Raise if a request of ``n_tokens`` KV tokens could never fit."""
+
+    def fits(self, prompt: np.ndarray, budget: int) -> bool:
+        """Whether :meth:`reserve` would take the request now."""
+        return True
+
+    def reserve(self, slot: int, prompt: np.ndarray, budget: int) -> dict | None:
+        """Claim the KV of a request about to take ``slot``: the
+        ``serve.lane.start`` span's metadata, or None (nothing claimed)."""
+        return {}
+
+    def register(self, slot: int, prompt: np.ndarray) -> None:
+        """Offer ``slot``'s reserved prompt to later prefix matches."""
+
+    def publish(
+        self, caches: Any, slot: int, scratch: Any, row: int, plen: int
+    ) -> tuple[Any, dict]:
+        """Install row ``row`` of a prefilled contiguous ``scratch`` (its
+        first ``plen`` positions are the prompt) as ``slot``'s KV.  Returns
+        the new caches and the ``serve.lane.install`` span's metadata."""
+        raise NotImplementedError
+
+    def release(self, caches: Any, slot: int) -> Any:
+        """Evict a published slot."""
+        return caches
+
+    def drop(self, slot: int) -> None:
+        """Return a reservation that was never published."""
+
+    def begin_step(self) -> None:
+        """Start a step's record of legally written blocks."""
+
+    def before_decode(self, caches: Any, slots: dict) -> Any:
+        """Prepare the live ``slots`` (slot -> state with ``emitted``)."""
+        return caches
+
+    def decode_work(self, slots: dict) -> dict:
+        """The layout's part of the ``serve.decode`` span's metadata."""
+        return {}
+
+    def audit(self, caches: Any) -> list[tuple[int, list[int]]]:
+        """Blocks corrupt since the last audit, with their owners' slots."""
+        return []
+
+    def live_refs(self) -> dict[int, int]:
+        """Block -> references held by reserved and published rows."""
+        return {}
+
+    def to_state(self) -> dict:
+        """The snapshot's ``rows`` and ``pool`` (JSON-safe)."""
+        return {"rows": {}, "pool": None}
+
+    def from_state(self, state: dict, caches: Any) -> None:
+        """Restore :meth:`to_state` output beside the restored ``caches``."""
+
+
+class ContiguousKV(KVLayout):
+    """One ``(slots, max_len)`` ring per layer, sized by the worst case.
+    Publishing masks the row's right-padded tail and scatters it into the
+    slot; eviction writes nothing, since the next admission overwrites the
+    whole slot."""
+
+    def __init__(self, cfg: ModelConfig, scfg: Any, stats: dict):
+        super().__init__(cfg, scfg, stats)
+        axes = slot_axes(cfg, self.max_len)
+
+        def slot_install(big, scratch, row, slot, true_len):
+            small = mask_prompt_tail(take_slot(scratch, row, axes), true_len)
+            return slot_store(big, small, slot, axes)
+
+        self._install = jax.jit(slot_install, donate_argnums=(0,))
+
+    def init_caches(self) -> Any:
+        return build_caches(self.cfg, self.batch, self.max_len)
+
+    def publish(self, caches, slot, scratch, row, plen):
+        caches = self._install(
+            caches, scratch, jnp.int32(row), jnp.int32(slot), jnp.int32(plen)
+        )
+        return caches, {}
+
+
+class PagedKV(KVLayout):
+    """The paged pool: a :class:`BlockPool` decides which physical blocks
+    each request owns, ``rows`` records them per published slot, and the
+    donated ``paged_*`` programs write the device.  With ``kv_checksum``
+    (or ABFT, which localizes through it) a host mirror of per-block sums
+    is audited against the blocks legally written (``touched``)."""
+
+    def __init__(self, cfg: ModelConfig, scfg: Any, stats: dict):
+        if not supports_paged(cfg):
+            raise ValueError(
+                f"kv_layout='paged' needs all-global attention; "
+                f"{cfg.name} has ring/recurrent/hybrid caches"
+            )
+        super().__init__(cfg, scfg, stats)
+        self.block_size = scfg.kv.block_size
+        self.prefix_sharing = scfg.kv.prefix_sharing
+        self.pool = BlockPool(scfg.resolved_num_blocks(), self.block_size)
+        self._reserved: dict[int, PagedRow] = {}
+        self._sink_row = np.zeros((self.max_len // self.block_size,), np.int32)
+        self.pack_row = jax.jit(paged_store_row_blocks, donate_argnums=(0,))
+        self.set_row = jax.jit(paged_set_row, donate_argnums=(0,))
+        self.copy_block = jax.jit(paged_copy_block, donate_argnums=(0,))
+        armed = scfg.durability.kv_checksum or scfg.kernel.abft != "off"
+        self._sums = jax.jit(pool_sums) if armed else None
+        self._mirror: np.ndarray | None = None
+        self.touched: set[int] = set()
+
+    def init_caches(self) -> Any:
+        caches = build_paged_caches(
+            self.cfg, self.batch, self.max_len, self.pool.num_blocks,
+            self.block_size,
+        )
+        self._rebaseline(caches)
+        return caches
+
+    def _rebaseline(self, caches: Any) -> None:
+        if self._sums is not None:
+            self._mirror = np.asarray(self._sums(caches))
+
+    def check_request(self, rid, n_tokens):
+        # never let one request outgrow the whole pool: its admission would
+        # wait forever for blocks that can't exist, and shrinking its budget
+        # would quietly diverge from the contiguous oracle.  With the
+        # default pool sizing (batch * max_len tokens) this never triggers.
+        cap = (self.pool.num_blocks - 1) * self.block_size
+        if n_tokens > cap:
+            raise ValueError(
+                f"request {rid} needs {n_tokens} KV tokens but the whole "
+                f"pool holds {cap}; grow num_blocks or shorten the request"
+            )
+
+    def _plan(self, prompt, budget):
+        """The one copy of admission's block arithmetic: the worst-case
+        reservation minus prefix aliases, plus a copy-on-write target when
+        the shared tail will be written.  Returns (free blocks taken, total
+        blocks, shared full blocks, shared tail or None, cow)."""
+        total = -(-(len(prompt) + budget) // self.block_size)
+        full, tail = [], None
+        if self.prefix_sharing:
+            full, tail = self.pool.match_prefix(prompt.tolist())
+        cow = tail is not None and budget > 1
+        need = total - len(full) - (tail is not None) + cow
+        return need, total, full, tail, cow
+
+    def fits(self, prompt, budget):
+        return self._plan(prompt, budget)[0] <= self.pool.free_blocks
+
+    def reserve(self, slot, prompt, budget):
+        """Retain the prefix aliases, allocate the rest, and set aside the
+        copy-on-write target, so the first divergent write can never be
+        starved by admissions racing it to the free list."""
+        need, total, full, tail, cow = self._plan(prompt, budget)
+        if need > self.pool.free_blocks:
+            return None
+        blocks = full + ([] if tail is None else [tail])
+        for b in blocks:
+            self.pool.retain(b)
+        while len(blocks) < total:
+            blocks.append(self.pool.alloc())
+        row = PagedRow(
+            blocks=blocks,
+            plen=len(prompt),
+            n_shared_full=len(full),
+            tail_shared=tail is not None,
+            cow_dst=self.pool.alloc() if cow else None,
+        )
+        self._reserved[slot] = row
+        hit = row.hit_tokens(self.block_size)
+        self.stats["prompt_tokens"] += row.plen
+        self.stats["prefix_hit_tokens"] += hit
+        return {"hit_tokens": hit}
+
+    def register(self, slot, prompt):
+        """Publish the reserved row's prompt blocks in the radix prefix
+        index.  Monolithic admission registers at reserve, since it
+        publishes within the same step; chunked admission at install, since
+        a block whose K/V is not packed yet must never be aliased."""
+        if not self.prefix_sharing:
+            return
+        row, bs, toks = self._reserved[slot], self.block_size, prompt.tolist()
+        n_full = row.plen // bs
+        prev = -1
+        for i in range(n_full):
+            self.pool.register(prev, tuple(toks[i * bs : (i + 1) * bs]), row.blocks[i])
+            prev = row.blocks[i]
+        tail = tuple(toks[n_full * bs :])
+        if tail and n_full < len(row.blocks):
+            self.pool.register(prev, tail, row.blocks[n_full])
+
+    def publish(self, caches, slot, scratch, row, plen):
+        """Set the slot's block table and length, then pack the scratch
+        row's prompt blocks into the pool, skipping those aliased from the
+        prefix index."""
+        r = self._reserved.pop(slot)
+        bs = self.block_size
+        table = np.full(self._sink_row.shape, SINK_BLOCK, np.int32)
+        table[: len(r.blocks)] = r.blocks
+        caches = self.set_row(
+            caches, jnp.int32(slot), jnp.asarray(table), jnp.int32(plen)
+        )
+        start = r.n_shared_full
+        n_pack = -(-plen // bs) - start - (1 if r.tail_shared else 0)
+        if n_pack > 0:
+            caches = self.pack_row(
+                caches,
+                {"k": scratch["k"], "v": scratch["v"]},
+                jnp.int32(row),
+                jnp.int32(start),
+                jnp.asarray(r.blocks[start : start + n_pack], jnp.int32),
+            )
+        self.rows[slot] = r
+        if self._sums is not None:
+            # aliased prefix blocks are untouched on the device, but marking
+            # them is a harmless over-approximation
+            self.touched.update(r.blocks)
+        return caches, {"packed": max(n_pack, 0)}
+
+    def release(self, caches, slot):
+        """Aim the row's device table at the sink (the always-full-batch
+        decode keeps writing through dead rows, and these blocks are about
+        to be reused) and return every block it owns, a pending CoW
+        reservation included."""
+        row = self.rows.pop(slot)
+        caches = self.set_row(
+            caches, jnp.int32(slot), jnp.asarray(self._sink_row), jnp.int32(0)
+        )
+        for b in row.owned():
+            self.pool.release(b)
+        return caches
+
+    def drop(self, slot):
+        for b in self._reserved.pop(slot).owned():
+            self.pool.release(b)
+
+    def begin_step(self):
+        self.touched = {SINK_BLOCK}
+
+    def before_decode(self, caches, slots):
+        """Give every live row still aliasing a shared prompt tail its
+        reserved private copy (its first divergent write lands at ``plen``,
+        inside that block), then mark the block each live row's decode
+        write lands in: position ``plen + emitted - 1``."""
+        bs = self.block_size
+        pending = [s for s in sorted(slots) if self.rows[s].cow_dst is not None]
+        if pending:
+            with jax.profiler.TraceAnnotation("serve.cow", n=len(pending)):
+                for s in pending:
+                    row = self.rows[s]
+                    lb = row.plen // bs
+                    src = row.blocks[lb]
+                    caches = self.copy_block(
+                        caches, jnp.int32(s), jnp.int32(lb), jnp.int32(src),
+                        jnp.int32(row.cow_dst),
+                    )
+                    self.pool.release(src)
+                    if self._sums is not None:
+                        self.touched.add(row.cow_dst)
+                    row.blocks[lb], row.cow_dst = row.cow_dst, None
+                    row.tail_shared = False
+        if self._sums is not None:
+            for s, st in slots.items():
+                row = self.rows[s]
+                self.touched.add(row.blocks[(row.plen + st.emitted - 1) // bs])
+        return caches
+
+    def decode_work(self, slots):
+        """The paged decode kernel's work items per layer this step, from
+        the host's rows (``decode_attention.paged_work_list`` counts the
+        same on the device from the lengths): one per block a live row
+        reads through its newest token, and one for each row that holds no
+        request, whose length is 0.  ``stats`` sums it beside the whole
+        (slots x blocks) grid."""
+        bs = self.block_size
+        live = sum(-(-(self.rows[s].plen + st.emitted) // bs) for s, st in slots.items())
+        work = self.batch - len(slots) + live
+        self.stats["decode_work_items"] += work
+        self.stats["decode_grid_full"] += self.batch * self.max_len // bs
+        return {"work": work}
+
+    def audit(self, caches):
+        """Recompute the per-block sums: a block that changed without a
+        legal write since the last audit is corrupt.  NaN sums compare
+        equal to themselves here, so an already-quarantined poisoned block
+        does not re-fire once it sits idle in the free list."""
+        if self._sums is None:
+            return []
+        sums = np.asarray(self._sums(caches))
+        prev, self._mirror = self._mirror, sums
+        changed = (sums != prev) & ~(np.isnan(sums) & np.isnan(prev))
+        if self.touched:
+            changed[list(self.touched)] = False
+        return [
+            (b, [s for s, row in self.rows.items() if b in row.owned()])
+            for b in np.nonzero(changed)[0].tolist()
+        ]
+
+    def live_refs(self):
+        rows = [*self.rows.values(), *self._reserved.values()]
+        return dict(collections.Counter(b for row in rows for b in row.owned()))
+
+    def to_state(self):
+        """Published rows only.  A reservation not yet published (a
+        mid-flight prefill lane) is released in the pool image: restore
+        requeues its request, which re-prefills it."""
+        pool = self.pool
+        if self._reserved:
+            pool = BlockPool.from_state(pool.to_state())
+            for row in self._reserved.values():
+                for b in row.owned():
+                    pool.release(b)
+        return {
+            "rows": {str(s): dataclasses.asdict(r) for s, r in self.rows.items()},
+            "pool": pool.to_state(),
+        }
+
+    def from_state(self, state, caches):
+        self.rows = {
+            int(s): PagedRow(
+                blocks=[int(b) for b in r["blocks"]],
+                plen=int(r["plen"]),
+                n_shared_full=int(r["n_shared_full"]),
+                tail_shared=bool(r["tail_shared"]),
+                cow_dst=None if r["cow_dst"] is None else int(r["cow_dst"]),
+            )
+            for s, r in state["rows"].items()
+        }
+        self._reserved = {}
+        self.pool = BlockPool.from_state(state["pool"])
+        self._rebaseline(caches)
+
+
+def make_layout(cfg: ModelConfig, scfg: Any, stats: dict) -> KVLayout:
+    """The engine's KV layout, chosen by ``scfg.kv.layout``."""
+    return {"contiguous": ContiguousKV, "paged": PagedKV}[scfg.kv.layout](
+        cfg, scfg, stats
     )

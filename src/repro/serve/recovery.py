@@ -81,11 +81,9 @@ from repro.serve.engine import (
     Engine,
     RequestStatus,
     ServeConfig,
-    _PagedRow,
     _ReqInfo,
     _SlotState,
 )
-from repro.serve.kvcache import BlockPool
 
 _FORMAT = 1
 
@@ -245,13 +243,12 @@ def _host_state(eng: Engine) -> dict:
     see a frozen image while the engine keeps stepping.
 
     A mid-flight chunked-prefill lane is serialized as its request
-    REQUEUED (WAITING, slot freed, committed blocks released in the
-    persisted pool image): the lane has published nothing — zero tokens,
-    no device block table or slot writes — so restore is a plain
-    re-prefill, bitwise identical by determinism."""
+    REQUEUED (WAITING, slot freed, its reservation released in the
+    persisted pool image by ``KVLayout.to_state``): the lane has published
+    nothing — zero tokens, no device block table or slot writes — so
+    restore is a plain re-prefill, bitwise identical by determinism."""
     free = list(eng._free)
     waiting = list(eng._waiting)
-    pool_state = eng.pool.to_state() if eng.pool is not None else None
     requeued: set[int] = set()
     lane = eng._lane
     if lane is not None:
@@ -261,13 +258,6 @@ def _host_state(eng: Engine) -> dict:
             key=lambda r: (-eng._reqs[r].priority, eng._reqs[r].seq),
         )
         requeued.add(lane.rid)
-        if pool_state is not None and lane.row is not None:
-            pool = BlockPool.from_state(pool_state)
-            for b in lane.row.blocks:
-                pool.release(b)
-            if lane.row.cow_dst is not None:
-                pool.release(lane.row.cow_dst)
-            pool_state = pool.to_state()
     reqs = []
     for info in eng._reqs.values():
         rec = _submit_record(info)
@@ -293,17 +283,7 @@ def _host_state(eng: Engine) -> dict:
             }
             for s, st in eng._slots.items()
         },
-        "rows": {
-            str(s): {
-                "blocks": list(row.blocks),
-                "plen": row.plen,
-                "n_shared_full": row.n_shared_full,
-                "tail_shared": row.tail_shared,
-                "cow_dst": row.cow_dst,
-            }
-            for s, row in eng._rows.items()
-        },
-        "pool": pool_state,
+        **eng.kv.to_state(),
     }
 
 
@@ -606,7 +586,8 @@ def _apply_snapshot(eng: Engine, snap: dict) -> None:
     eng._next_rid = int(h["next_rid"])
     eng._next_seq = int(h["next_seq"])
     eng._stalled = int(h["stalled"])
-    eng.stats = {**eng.stats, **{k: int(v) for k, v in h["stats"].items()}}
+    # in place: the KV layout counts into this same dict
+    eng.stats.update({k: int(v) for k, v in h["stats"].items()})
     eng._free = deque(int(s) for s in h["free"])
     eng._waiting = [int(r) for r in h["waiting"]]
     eng._reqs = {}
@@ -640,18 +621,7 @@ def _apply_snapshot(eng: Engine, snap: dict) -> None:
         )
         for s, st in h["slots"].items()
     }
-    eng._rows = {
-        int(s): _PagedRow(
-            blocks=[int(b) for b in row["blocks"]],
-            plen=int(row["plen"]),
-            n_shared_full=int(row["n_shared_full"]),
-            tail_shared=bool(row["tail_shared"]),
-            cow_dst=None if row["cow_dst"] is None else int(row["cow_dst"]),
-        )
-        for s, row in h["rows"].items()
-    }
-    if eng.pool is not None:
-        eng.pool = BlockPool.from_state(h["pool"])
+    eng.kv.from_state(h, eng.caches)
 
 
 def _apply_records(
@@ -798,7 +768,6 @@ def restore_engine(
     # _activate's replay path on re-admission
     for st in eng._slots.values():
         st.replay = len(eng._outputs[st.rid])
-    eng._refresh_kv_sums()
 
     if scfg.snapshot_dir:
         RecoveryManager.attach(

@@ -15,7 +15,10 @@ Seeds are fixed so CI is reproducible; crank the trace count locally with
 ``FUZZ_EXAMPLES=N make test-fuzz``.
 """
 
+from types import SimpleNamespace
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -23,7 +26,13 @@ from conftest import fuzz_examples
 from repro.arch.model_zoo import build
 from repro.configs.registry import get
 from repro.serve import kvcache
-from repro.serve.engine import Engine, Request, ServeConfig
+from repro.serve.engine import (
+    Engine,
+    KVConfig,
+    Request,
+    SchedulerConfig,
+    ServeConfig,
+)
 
 
 @pytest.fixture(scope="module")
@@ -83,21 +92,21 @@ def _random_workload(rng, cfg, n, max_len, *, share_p=0.5, prefix_pool=3):
 
 
 def _check_pool(eng):
-    eng.pool.assert_invariants(eng.live_block_refs())
+    eng.pool.assert_invariants(eng.kv.live_refs())
 
 
 def _check_device_tables(eng):
     """The device block tables of live rows must mirror the host
-    ownership (`_PagedRow.blocks`), with every entry past the reserved
+    ownership (`kvcache.PagedRow.blocks`), with every entry past the reserved
     span aimed at the sink."""
     tables = np.asarray(eng.caches["table"][0])
-    for slot, row in eng._rows.items():
+    for slot, row in eng.kv.rows.items():
         want = np.full((tables.shape[1],), kvcache.SINK_BLOCK, np.int32)
         nb = len(row.blocks)
         want[:nb] = row.blocks
         got = tables[slot]
         # a pending CoW is the one legal divergence: the device row still
-        # aims at the shared tail until _resolve_cow repoints it
+        # aims at the shared tail until before_decode repoints it
         if row.cow_dst is not None:
             lb = row.plen // eng.scfg.block_size
             want[lb] = got[lb]
@@ -212,7 +221,7 @@ def test_prefix_sharing_aliases_and_cow(smol):
             if rows_before is None:
                 rows_before = {
                     s: (list(r.blocks), r.tail_shared, r.cow_dst)
-                    for s, r in eng._rows.items()
+                    for s, r in eng.kv.rows.items()
                 }
 
     eng.step(on_token=Snap())  # admission snapshot fires at first token
@@ -228,7 +237,7 @@ def test_prefix_sharing_aliases_and_cow(smol):
     # request 2 extends past the tail content: its tail block is private
     assert blocks[s2][2] != blocks[s0][2]
     # after the first decode step the twin's CoW resolved: private tail
-    row1 = eng._rows[s1]
+    row1 = eng.kv.rows[s1]
     assert row1.cow_dst is None and not row1.tail_shared
     assert row1.blocks[2] == cows[s1]
     assert eng.pool.refcount[blocks[s0][2]] == 1  # back to creator-only
@@ -270,7 +279,7 @@ def test_evict_then_readmit_same_slot(smol):
 def test_cancel_during_pending_cow_releases_reservation(smol):
     """Adversarial interleaving: an exact-prompt twin is cancelled from
     its own first-token callback — AFTER admission reserved its CoW block
-    but BEFORE ``_resolve_cow`` ran.  The eviction must release both the
+    but BEFORE ``PagedKV.before_decode`` ran.  The eviction must release both the
     shared tail reference and the pending CoW reservation, leaving the
     creator untouched."""
     cfg, params = smol
@@ -285,7 +294,7 @@ def test_cancel_during_pending_cow_releases_reservation(smol):
     def cb(rid, tok, idx, done):
         if rid == 1 and idx == 0:
             slot = next(s for s, st in eng._slots.items() if st.rid == 1)
-            row = eng._rows[slot]
+            row = eng.kv.rows[slot]
             assert row.tail_shared and row.cow_dst is not None
             seen["cow"] = row.cow_dst
             seen["tail"] = row.blocks[2]
@@ -581,3 +590,135 @@ def test_serve_engine_stress_no_leak_deterministic(smol):
     a = drive(order_seed=0)
     b = drive(order_seed=1)  # different arrival order, same requests
     assert a == b, "outputs depend on arrival order"
+
+
+# ------------------------------------------------------ the layout seam --
+
+
+@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+def test_layout_seam_contract(smol, layout):
+    """The KV layout driven through its own interface, as the engine drives
+    it: reserve -> publish -> before_decode -> release (and drop).  After
+    every operation the pool's refcounts equal ``live_refs()`` (paged), and
+    each published row reads back bitwise equal to the same scratch row
+    written directly: ``kvcache.slot_store`` of its masked row, or
+    ``paged_set_row`` + ``paged_store_row_blocks``."""
+    cfg, params = smol
+    bs, batch, max_len, lpad, budget = 16, 3, 64, 48, 6
+    kv_cfg = KVConfig(layout="paged", block_size=bs) if layout == "paged" else KVConfig()
+    scfg = ServeConfig(
+        max_len=max_len, scheduler=SchedulerConfig(batch=batch), kv=kv_cfg
+    )
+    stats = dict.fromkeys(
+        ["prompt_tokens", "prefix_hit_tokens", "decode_work_items", "decode_grid_full"], 0
+    )
+    kv = kvcache.make_layout(cfg, scfg, stats)
+    paged = kv.pool is not None
+    assert paged == (layout == "paged")
+
+    def check():
+        if paged:
+            kv.pool.assert_invariants(kv.live_refs())
+
+    # a bucketed prefill of two prompts right-padded to 48: A holds 2 full
+    # blocks and a 5-token tail, B one block and a 4-token tail
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, cfg.vocab, n).astype(np.int32) for n in (37, 20)]
+    toks = np.zeros((2, lpad), np.int32)
+    for i, p in enumerate(prompts):
+        toks[i, : len(p)] = p
+    _, scratch = build(cfg).prefill(
+        params, jnp.asarray(toks), kvcache.build_caches(cfg, 2, max_len),
+        last_index=jnp.asarray([len(p) - 1 for p in prompts], jnp.int32),
+    )
+    # slot -> (scratch row, prompt); slot 2 is A's exact twin, which
+    # aliases every block of A's prompt
+    admits = {0: (0, prompts[0]), 1: (1, prompts[1]), 2: (0, prompts[0])}
+    caches = kv.init_caches()
+    check()
+
+    hits = {}
+    for slot, (_, p) in admits.items():
+        meta = kv.reserve(slot, p, budget)
+        kv.register(slot, p)
+        hits[slot] = meta.get("hit_tokens")
+        check()
+    assert hits == ({0: 0, 1: 0, 2: 37} if paged else {0: None, 1: None, 2: None})
+    packed = {}
+    for slot, (row, p) in admits.items():
+        caches, meta = kv.publish(caches, slot, scratch, row, len(p))
+        packed[slot] = meta.get("packed")
+        check()
+    assert packed == ({0: 3, 1: 2, 2: 0} if paged else {0: None, 1: None, 2: None})
+    assert sorted(kv.rows) == ([0, 1, 2] if paged else [])
+
+    # the direct writes the published rows must equal, bitwise, and each
+    # slot's prompt K/V read back against its scratch row
+    if paged:
+        want = kvcache.build_paged_caches(cfg, batch, max_len, kv.pool.num_blocks, bs)
+        kv_only = {"k": scratch["k"], "v": scratch["v"]}
+        for slot, (row, p) in admits.items():
+            blocks = kv.rows[slot].blocks
+            table = np.full((max_len // bs,), kvcache.SINK_BLOCK, np.int32)
+            table[: len(blocks)] = blocks
+            want = kvcache.paged_set_row(want, slot, jnp.asarray(table), len(p))
+            if slot < 2:
+                n = -(-len(p) // bs)
+                want = kvcache.paged_store_row_blocks(
+                    want, kv_only, row, 0, jnp.asarray(blocks[:n], jnp.int32)
+                )
+        for slot, (row, p) in admits.items():
+            n = -(-len(p) // bs)
+            for name in ("k", "v"):
+                got = kvcache.unpack_blocks(
+                    caches[name + "pool"][:, np.asarray(kv.rows[slot].blocks[:n])],
+                    cfg.n_kv_heads, cfg.resolved_head_dim,
+                ).reshape(cfg.n_layers, n * bs, cfg.n_kv_heads, -1)
+                assert np.array_equal(got[:, : len(p)], scratch[name][:, row, : len(p)])
+    else:
+        axes = kvcache.slot_axes(cfg, max_len)
+        want = kvcache.build_caches(cfg, batch, max_len)
+        for slot, (row, p) in admits.items():
+            small = kvcache.take_slot(scratch, row, axes)
+            small = kvcache.mask_prompt_tail(small, len(p))
+            want = kvcache.slot_store(want, small, slot, axes)
+            for name in ("k", "v"):
+                assert np.array_equal(
+                    caches[name][:, slot, : len(p)], scratch[name][:, row, : len(p)]
+                )
+            assert (np.asarray(caches["pos"])[:, slot, len(p) :] == 10**9).all()
+    jax.tree.map(np.testing.assert_array_equal, caches, want)
+
+    # the twin's first divergent write is about to land in the shared tail
+    slots = {s: SimpleNamespace(emitted=1) for s in admits}
+    before = caches
+    caches = kv.before_decode(caches, slots)
+    check()
+    work = kv.decode_work(slots)
+    if paged:
+        tail0, tail2 = kv.rows[0].blocks[2], kv.rows[2].blocks[2]
+        assert tail2 != tail0 and kv.rows[2].cow_dst is None
+        assert kv.pool.refcount[tail0] == 1
+        assert np.array_equal(caches["kpool"][:, tail2], caches["kpool"][:, tail0])
+        assert np.asarray(caches["table"])[0, 2, 2] == tail2
+        # each row reads its blocks through the position its token lands at
+        assert work == {"work": 3 + 2 + 3}
+        assert stats["decode_work_items"] == 8
+    else:
+        assert caches is before and work == {}
+        assert stats["decode_work_items"] == 0
+
+    for slot in admits:
+        caches = kv.release(caches, slot)
+        check()
+    # a reservation that never publishes is dropped without device writes
+    assert kv.reserve(1, prompts[0], budget) is not None
+    check()
+    kv.drop(1)
+    check()
+    assert kv.rows == {} and kv.live_refs() == {}
+    if paged:
+        assert kv.pool.free_blocks == kv.pool.num_blocks - 1
+        assert not np.asarray(caches["table"]).any()
+        assert not np.asarray(caches["len"]).any()
+        assert stats["prompt_tokens"] == 37 + 20 + 37 + 37

@@ -23,7 +23,14 @@ import pytest
 from repro.arch.model_zoo import build
 from repro.configs.registry import get
 from repro.serve import chaos, kvcache, recovery
-from repro.serve.engine import Engine, Request, RequestStatus, ServeConfig
+from repro.serve.engine import (
+    Engine,
+    KVConfig,
+    Request,
+    RequestStatus,
+    SchedulerConfig,
+    ServeConfig,
+)
 
 MAX_LEN = 64
 BS = 8
@@ -269,7 +276,7 @@ def test_nan_guard_quarantines_poisoned_request(smol, layout):
     st = eng._slots[slot]
     assert st.emitted >= 2
     if layout == "paged":
-        row = eng._rows[slot]
+        row = eng.kv.rows[slot]
         pos = row.plen + st.emitted - 2  # last decode-written position
         at, lanes = kvcache.token_slot(
             pos % BS, cfg.n_kv_heads, cfg.resolved_head_dim, BS
@@ -310,7 +317,7 @@ def test_kv_checksum_detects_finite_corruption(smol, paged_oracle):
     for _ in range(3):
         eng.step()
     slot = eng._slot_of(1)
-    row = eng._rows[slot]
+    row = eng.kv.rows[slot]
     at, lanes = kvcache.token_slot(0, cfg.n_kv_heads, cfg.resolved_head_dim, BS)
     eng.caches["vpool"] = (
         eng.caches["vpool"].at[:, row.blocks[0], at, lanes].add(1.0)
@@ -431,3 +438,77 @@ def test_crash_mid_prefill_restores_bitwise(smol, tmp_path):
     assert len(eng2._outputs[mid_rid]) == 0
     _drain_bitwise(eng2, reqs, want)
     eng2.close()
+
+
+@pytest.mark.parametrize("layout", ["paged", "contiguous"])
+def test_snapshot_format_is_pinned(smol, layout):
+    """The snapshot's on-disk keys are a contract with every snapshot
+    already written: the config fingerprint, the host state (a paged
+    row's fields and the pool image among it) and the npz arrays.  It is
+    staged with a chunked-prefill lane mid-flight, whose request the host
+    state requeues and whose blocks the pool image holds free."""
+    cfg, params = smol
+    kv = (
+        KVConfig(layout="paged", block_size=BS)
+        if layout == "paged"
+        else KVConfig(decode_block=BS)
+    )
+    scfg = ServeConfig(
+        max_len=MAX_LEN, temperature=0.8, seed=3, kv=kv,
+        scheduler=SchedulerConfig(batch=2, prefill_chunk=BS, token_budget=BS),
+    )
+    eng = Engine(cfg, params, scfg)
+    prompts = np.random.default_rng(7).integers(0, cfg.vocab, (3, 40))
+    for i, p in enumerate(prompts.astype(np.int32)):
+        eng.submit(Request(p, 5, request_id=i))
+    for _ in range(7):
+        eng.step()
+    assert eng._slots and eng._lane is not None
+    staged = recovery._stage(eng)
+    meta = staged["meta"]
+    assert list(meta) == [
+        "format", "step", "n_cache_leaves", "scfg", "host", "leaves",
+    ]
+    assert meta["format"] == 1
+    assert list(meta["scfg"]) == [
+        "batch", "max_len", "temperature", "seed", "prefill_bucket",
+        "matmul", "attention", "kv_layout", "block_size", "num_blocks",
+        "prefix_sharing", "decode_block",
+    ]
+    assert meta["scfg"]["kv_layout"] == layout
+    host = meta["host"]
+    assert list(host) == [
+        "step_no", "next_rid", "next_seq", "stalled", "stats", "free",
+        "waiting", "reqs", "outputs", "slots", "rows", "pool",
+    ]
+    assert eng._lane.rid in host["waiting"]
+    assert eng._lane.slot in host["free"]
+    assert list(host["slots"]) == [str(s) for s in eng._slots]
+    n = meta["n_cache_leaves"]
+    assert n == len(jax.tree_util.tree_leaves(eng.caches))
+    assert list(staged["arrays"]) == [f"cache_{i:04d}" for i in range(n)] + [
+        "cur_tok"
+    ]
+    assert list(meta["leaves"]) == list(staged["arrays"])
+    if layout == "contiguous":
+        assert meta["scfg"]["num_blocks"] is None
+        assert host["rows"] == {} and host["pool"] is None
+        return
+    assert meta["scfg"]["num_blocks"] == scfg.resolved_num_blocks()
+    assert n == 4  # kpool, len, table, vpool
+    assert list(host["rows"]) == list(host["slots"])
+    for row in host["rows"].values():
+        assert list(row) == [
+            "blocks", "plen", "n_shared_full", "tail_shared", "cow_dst",
+        ]
+    pool = host["pool"]
+    assert list(pool) == [
+        "num_blocks", "block_size", "refcount", "free", "external", "index",
+    ]
+    # the image holds the published rows' blocks and nothing of the lane's
+    refs = {}
+    for row in host["rows"].values():
+        for b in row["blocks"]:
+            refs[b] = refs.get(b, 0) + 1
+    image = kvcache.BlockPool.from_state(pool)
+    image.assert_invariants(refs)

@@ -165,10 +165,10 @@ def test_program_names_the_trace_readers_match(smol):
         "decode_fn": eng._decode.lower(eng.params, i32(SLOTS, 1), eng.caches,
                                        key(SLOTS, 2), i32(SLOTS)),
         "chunk_fn": eng._chunk_step.lower(eng.params, i32(1, CHUNK), scratch, i32(1), key(2)),
-        "paged_store_row_blocks": eng._pack_row.lower(
+        "paged_store_row_blocks": eng.kv.pack_row.lower(
             eng.caches, {"k": scratch["k"], "v": scratch["v"]}, i32(), i32(), i32(2)),
-        "paged_set_row": eng._set_row.lower(eng.caches, i32(), i32(n_blk), i32()),
-        "paged_copy_block": eng._cow.lower(eng.caches, i32(), i32(), i32(), i32()),
+        "paged_set_row": eng.kv.set_row.lower(eng.caches, i32(), i32(n_blk), i32()),
+        "paged_copy_block": eng.kv.copy_block.lower(eng.caches, i32(), i32(), i32(), i32()),
         "_lambda": eng._fresh_scratch.lower(),
     }
     for name, low in lowered.items():

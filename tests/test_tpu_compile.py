@@ -276,11 +276,11 @@ def test_engine_paged_programs_keep_the_pool_in_place(one_chip, monkeypatch, siz
         on_chip(params), on_chip(jnp.zeros((1, scfg.prefill_chunk), jnp.int32)), scratch,
         on_chip(jnp.zeros((1,), jnp.int32)), on_chip(jnp.zeros((2,), jnp.uint32)),
     ).compile()
-    pack = eng._pack_row.lower(
+    pack = eng.kv.pack_row.lower(
         caches, {"k": scratch["k"], "v": scratch["v"]}, i32, i32,
         on_chip(jnp.zeros((4,), jnp.int32)),
     ).compile()
-    cow = eng._cow.lower(caches, i32, i32, i32, i32).compile()
+    cow = eng.kv.copy_block.lower(caches, i32, i32, i32, i32).compile()
     monkeypatch.undo()
 
     # a weight's relayout (the tied embedding, read by the gather and the
