@@ -7,7 +7,7 @@ the slot's KV cache:
 
   * grid ``(batch, kv_splits)``: rows are independent; the KV split axis
     is innermost and sequential, so the online-softmax partials (running
-    max / normalizer / fp32 accumulator, one set per kv head) live in VMEM
+    max / normalizer / fp32 accumulator, one set per score row) live in VMEM
     scratch and are combined across splits without materializing
     per-split outputs.
   * per-row KV **lengths are a scalar-prefetch operand** (SMEM, available
@@ -17,16 +17,19 @@ the slot's KV cache:
     block indices elide the HBM->VMEM copy, so each slot only *reads*
     ``ceil(len/bk)`` KV blocks.  Dead blocks also skip compute via
     ``pl.when``.
-  * GQA is resolved **inside** the kernel: a K/V tile is ``(bk, KV, d)``
-    — every kv head of one split — and a static loop over heads scores
-    each head's ``(G, d)`` query group against its own ``(bk, d)`` slice,
-    so KV is fetched once and never broadcast G-fold beforehand.  Tiling
-    all heads at once is also what the TPU compiler demands: a block's
-    last two dims must be (8, 128)-divisible or equal to the array's, and
-    ``(1, d)`` against ``(KV, d)`` is neither.
+  * GQA is resolved **inside** the kernel, in **one score dot and one
+    value dot** a split: a K/V tile is ``(bk, KV * d)`` — every kv head of
+    one split, heads side by side in the lanes — and the query is laid out
+    block-diagonally, ``(KV * G, KV * d)``, with head ``h``'s ``(G, d)``
+    group at rows ``h * G`` and lanes ``h * d`` and zeros elsewhere, once
+    a row.  ``qbd @ k.T`` scores every head against its own keys (the
+    zeros cancel the others), one online-softmax update runs over the
+    ``(KV * G, bk)`` tile, and ``p @ v`` puts each score row's values in its
+    head's lanes, where the last split reads them out.  KV is fetched once
+    and never broadcast G-fold (see :func:`_decode_step`).
 
-k/v come in the serve engine's native cache layout ``(B, S, KV, d)`` so the
-donated decode loop hands the ring buffers to the kernel with zero copies.
+k/v come in the serve engine's cache layout ``(B, S, KV, d)``, which the
+kernel views as ``(B, S, KV * d)``.
 
 Masking contract: a row's live keys are exactly cache slots
 ``[0, lengths[b])``, with ``lengths`` clamped to ``[1, S]`` — the serve
@@ -62,15 +65,18 @@ from an item to its row and through the table to its physical block.  A
 row's items are consecutive, so its carry resets at its first block and
 its output is written once, at its last.  Reading a layer by index, the
 kernel takes the decode program's pools as they are, with no per-layer
-slice.  The body takes each head's ``r`` lane slices of a packed block
-(one per token parity) through the same online-softmax update.  Because
-the KV split boundary is the block boundary, the paged recurrence visits
-the same logical key ranges in the same order as the dense kernel at ``bk
-== block_size``; within a block the kernel sums the ``r`` token parities
-separately (so it matches the dense kernel to rounding, exactly at ``r ==
-1``), while the XLA twin unpacks the block and is bitwise equal to the
-dense twin, which is what lets the contiguous serve engine act as the
-paged engine's differential oracle on CPU.  An optional ``window``
+slice.  The body is the dense kernel's with ``r`` token parities: a packed
+row holds ``r`` tokens' heads side by side, so the block-diagonal query is
+``(r * KV * G, r * KV * d)``, score row ``(t, h, g)`` scores the block's
+keys ``t, t + r, ...``, the running max of a ``(h, g)`` is taken over its
+``r`` parity rows before exponentiating, and the parity rows' sums are
+added at the row's last item.  Because the KV split boundary is the block
+boundary, the paged recurrence visits the same logical key ranges in the
+same order as the dense kernel at ``bk == block_size``; within a block the
+kernel sums the ``r`` token parities separately (so it matches the dense
+kernel to rounding), while the XLA twin unpacks the block and is bitwise
+equal to the dense twin, which is what lets the contiguous serve engine act
+as the paged engine's differential oracle on CPU.  An optional ``window``
 additionally masks ``k_idx <= length - 1 - window`` for sliding-window
 rows (position == logical index in the paged layout; there is no ring).
 """
@@ -88,7 +94,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.flash_attention.flash_attention import (
     NEG_INF,
-    finalize_out,
     last_live_block,
     reset_carry,
 )
@@ -110,84 +115,99 @@ def _decode_step(
     j,                            # which KV split of the row this step reads
     last,                         # this step ends the row: finalize into o_ref
     q_ref,                        # (1, KV, G, d)
-    parts,                        # h -> [(k (n, d), v (n, d), stride, first)]
+    k, v,                         # (bk // r, r * KV * d): r tokens to a row
     o_ref,                        # (1, KV, G, d)
-    m_ref, l_ref, acc_ref,        # VMEM scratch: (KV, G), (KV, G), (KV, G, d) fp32
-    *, bk: int, scale: float, window: int | None,
+    qbd_ref,                      # VMEM scratch (r * KV * G, r * KV * d), q's dtype
+    m_ref, l_ref, acc_ref,        # VMEM scratch: (r*KV*G, 1) x 2, (r*KV*G, r*KV*d) f32
+    *, r: int, bk: int, scale: float, window: int | None,
 ):
-    """One KV split of one row, shared by the dense and paged kernels.  A
-    static loop over kv heads runs the online-softmax update per head over
-    the head's K/V slices ``parts(h)``: part ``(k, v, stride, first)`` holds
-    the block's keys ``first, first + stride, ...``.  The dense kernel has
-    one part per head (stride 1); the paged kernel one per token of a packed
-    pool row.  The running max covers every part before any is exponentiated,
-    so the update is the one-part update with its sums split by part.  The
-    caller names the split ``j`` and whether the row ends here: the dense
-    grid walks ``(row, split)``, the paged grid a list of work items."""
+    """One KV split of one row, shared by the dense (``r == 1``) and paged
+    kernels: every kv head and token parity in one score dot and one value
+    dot.  Token ``t`` of the split sits at row ``t // r`` of ``k``/``v``,
+    head ``h`` at lanes ``c * d ... + d`` with ``c = (t % r) * KV + h``.
+
+    At the row's first split the query is laid out block-diagonally in
+    ``qbd_ref``: score row ``(t, h, g)`` (``c * G + g``) holds ``q[h, g]`` at
+    block ``c``'s lanes and zeros elsewhere, and later splits of the row
+    reuse it.  ``qbd @ k.T`` is then the ``(r * KV * G, bk // r)`` score tile,
+    row ``(t, h, g)`` and column ``p`` scoring key ``j * bk + p * r + t``;
+    one online-softmax update runs over it, and ``p @ v`` lands each row's
+    values in its own block of lanes (the other blocks are never read).
+    The running max is one per ``(h, g)``, taken over its ``r`` parity rows
+    before any score is exponentiated, so every parity row shares one
+    scale; the normalizer and accumulator are kept per score row, and the
+    parity rows are summed once, at the row's last split.  The caller
+    names the split ``j`` and whether the row ends here: the dense grid
+    walks ``(row, split)``, the paged grid a list of work items."""
+    KV, G, d = q_ref.shape[1:]
+    n = KV * G                            # score rows of one token parity
 
     @pl.when(j == 0)
     def _init():
         reset_carry(m_ref, l_ref, acc_ref)
+        qbd_ref[...] = jnp.zeros_like(qbd_ref)
+        for c in range(r * KV):           # static: one block per (t, h)
+            qbd_ref[c * G:(c + 1) * G, c * d:(c + 1) * d] = q_ref[0, c % KV]
 
     @pl.when(j * bk < length)
     def _live():
-        for h in range(q_ref.shape[1]):     # static: one pass per kv head
-            q = q_ref[0, h]                   # (G, d)
-            ss, vs = [], []
-            for k, v, stride, first in parts(h):
-                s = jax.lax.dot_general(
-                    q, k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                ) * scale                     # (G, n)
-                k_idx = j * bk + first + stride * jax.lax.broadcasted_iota(
-                    jnp.int32, s.shape, 1
-                )
-                ss.append(jnp.where(_live_keys(k_idx, length, window), s, NEG_INF))
-                vs.append(v)
-
-            m_prev = m_ref[h]
-            m_new = functools.reduce(
-                jnp.maximum, [jnp.max(s, axis=1) for s in ss], m_prev
+        s = jax.lax.dot_general(
+            qbd_ref[...], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale                         # (r * KV * G, bk // r)
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        parity = sum((row >= t * n).astype(jnp.int32) for t in range(1, r))
+        k_idx = j * bk + parity + r * jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1
+        )
+        s = jnp.where(_live_keys(k_idx, length, window), s, NEG_INF)
+        m_prev = m_ref[...]
+        m_ref[...] = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        if r > 1:                         # one max over a (h, g)'s parities
+            m = functools.reduce(
+                jnp.maximum, [m_ref[t * n:(t + 1) * n] for t in range(r)]
             )
-            ps = [jnp.exp(s - m_new[:, None]) for s in ss]
-            corr = jnp.exp(m_prev - m_new)
-            l_ref[h] = l_ref[h] * corr + functools.reduce(
-                operator.add, [jnp.sum(p, axis=1) for p in ps]
-            )
-            m_ref[h] = m_new
-            acc_ref[h] = acc_ref[h] * corr[:, None] + functools.reduce(
-                operator.add,
-                [
-                    jax.lax.dot_general(
-                        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    )
-                    for p, v in zip(ps, vs)
-                ],
-            )
+            for t in range(r):
+                m_ref[t * n:(t + 1) * n] = m
+        m_new = m_ref[...]
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
     @pl.when(last)
     def _store():
-        finalize_out(o_ref, l_ref, acc_ref)
+        for h in range(KV):
+            cs = [t * KV + h for t in range(r)]
+            l = functools.reduce(
+                operator.add, [l_ref[c * G:(c + 1) * G] for c in cs]
+            )
+            acc = functools.reduce(operator.add, [
+                acc_ref[c * G:(c + 1) * G, c * d:(c + 1) * d] for c in cs
+            ])
+            o_ref[0, h] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
-def _decode_scratch(KV: int, G: int, d: int) -> list:
+def _decode_scratch(q: jax.Array, r: int) -> list:
+    _, KV, G, d = q.shape
+    n, lanes = r * KV * G, r * KV * d
     return [
-        pltpu.VMEM((KV, G), jnp.float32),
-        pltpu.VMEM((KV, G), jnp.float32),
-        pltpu.VMEM((KV, G, d), jnp.float32),
+        pltpu.VMEM((n, lanes), q.dtype),
+        pltpu.VMEM((n, 1), jnp.float32),
+        pltpu.VMEM((n, 1), jnp.float32),
+        pltpu.VMEM((n, lanes), jnp.float32),
     ]
 
 
 def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, *refs, n_k: int, **kw):
-    # lens_ref: SMEM (B,) int32 scalar-prefetch; k/v blocks (1, bk, KV, d)
-    def parts(h):
-        return [(k_ref[0, :, h, :], v_ref[0, :, h, :], 1, 0)]
-
+    # lens_ref: SMEM (B,) int32 scalar-prefetch; k/v blocks (1, bk, KV * d)
     j = pl.program_id(1)
     _decode_step(
-        lens_ref[pl.program_id(0)], j, j == n_k - 1, q_ref, parts, *refs,
-        window=None, **kw,
+        lens_ref[pl.program_id(0)], j, j == n_k - 1, q_ref, k_ref[0], v_ref[0],
+        *refs, r=1, window=None, **kw,
     )
 
 
@@ -208,7 +228,7 @@ def flash_decode_pallas(
     lengths = jnp.clip(lengths.astype(jnp.int32), 1, S)
 
     def kv_block(b, j, lens):
-        return (b, jnp.minimum(j, last_live_block(lens[b], bk)), 0, 0)
+        return (b, jnp.minimum(j, last_live_block(lens[b], bk)), 0)
 
     def row(b, j, lens):
         return (b, 0, 0, 0)
@@ -218,11 +238,11 @@ def flash_decode_pallas(
         grid=(B, n_k),
         in_specs=[
             pl.BlockSpec((1, KV, G, d), row),
-            pl.BlockSpec((1, bk, KV, d), kv_block),
-            pl.BlockSpec((1, bk, KV, d), kv_block),
+            pl.BlockSpec((1, bk, KV * d), kv_block),
+            pl.BlockSpec((1, bk, KV * d), kv_block),
         ],
         out_specs=pl.BlockSpec((1, KV, G, d), row),
-        scratch_shapes=_decode_scratch(KV, G, d),
+        scratch_shapes=_decode_scratch(q, 1),
     )
     kern = functools.partial(
         _decode_kernel, bk=bk, n_k=n_k, scale=scale,
@@ -233,7 +253,7 @@ def flash_decode_pallas(
         out_shape=jax.ShapeDtypeStruct((B, KV, G, d), q.dtype),
         interpret=interpret,
         name="decode_attention",
-    )(lengths, q, k, v)
+    )(lengths, q, k.reshape(B, S, KV * d), v.reshape(B, S, KV * d))
 
 
 def decode_attention_xla(
@@ -314,13 +334,12 @@ def paged_work_list(
 
 
 def _paged_decode_kernel(work_ref, n_work_ref, lens_ref, table_ref, layer_ref,
-                         q_ref, k_ref, v_ref, *refs, r: int, n_blk: int, **kw):
+                         q_ref, k_ref, v_ref, *refs, n_blk: int, **kw):
     # scalar prefetch (SMEM): work (W,), n_work (1,), lens (B,), table
     # (B * n_blk,), layer (1,) int32; the table and layer are only read by
     # the index maps.  Grid step i runs work item work[i] = b * n_blk + j.
     # k/v blocks are one packed pool block (1, bs // r, r * KV * d): token
     # t of the block, head h sits at row t // r, lanes (t % r) * KV * d + h * d.
-    KV, d = q_ref.shape[1], q_ref.shape[3]
     i = pl.program_id(0)
     item = work_ref[i]
     b = item // n_blk
@@ -328,19 +347,9 @@ def _paged_decode_kernel(work_ref, n_work_ref, lens_ref, table_ref, layer_ref,
     # differs, or at the list's last item (the padded tail repeats it)
     nxt = work_ref[jnp.minimum(i + 1, work_ref.shape[0] - 1)]
     last = (i == n_work_ref[0] - 1) | (nxt // n_blk != b)
-
-    def parts(h):
-        return [
-            (
-                k_ref[0, :, pl.ds(t * KV * d + h * d, d)],
-                v_ref[0, :, pl.ds(t * KV * d + h * d, d)],
-                r,
-                t,
-            )
-            for t in range(r)
-        ]
-
-    _decode_step(lens_ref[b], item % n_blk, last, q_ref, parts, *refs, **kw)
+    _decode_step(
+        lens_ref[b], item % n_blk, last, q_ref, k_ref[0], v_ref[0], *refs, **kw
+    )
 
 
 def flash_decode_paged_pallas(
@@ -386,7 +395,7 @@ def flash_decode_paged_pallas(
             pl.BlockSpec((None, 1, rows, lanes), kv_block),
         ],
         out_specs=pl.BlockSpec((1, KV, G, d), row),
-        scratch_shapes=_decode_scratch(KV, G, d),
+        scratch_shapes=_decode_scratch(q, r),
     )
     kern = functools.partial(
         _paged_decode_kernel,

@@ -188,21 +188,7 @@ def decode_attention(
     if impl == "xla":
         return decode_attention_xla(q, k, v, lengths, bk=bk_)
     interp = _should_interpret() if interpret is None else interpret
-    return _run_decode_kernel(
-        q,
-        lambda qp: flash_decode_pallas(qp, k, v, lengths, bk=bk_, interpret=interp),
-        interp,
-    )
-
-
-def _run_decode_kernel(q, kern, interp: bool):
-    """Shared decode-kernel epilogue: sublane-align the q group axis on TPU
-    (pad G up to a multiple of 8, slice the pad back off the output)."""
-    G = q.shape[2]
-    Gp = G if interp else -(-G // 8) * 8
-    qp = q if Gp == G else jnp.pad(q, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
-    out = kern(qp)
-    return out if Gp == G else out[:, :, :G]
+    return flash_decode_pallas(q, k, v, lengths, bk=bk_, interpret=interp)
 
 
 def decode_attention_paged(
@@ -235,11 +221,7 @@ def decode_attention_paged(
             q, kpool, vpool, tables, lengths, layer, window=window
         )
     interp = _should_interpret() if interpret is None else interpret
-    return _run_decode_kernel(
-        q,
-        lambda qp: flash_decode_paged_pallas(
-            qp, kpool, vpool, tables, lengths, layer,
-            work=work, window=window, interpret=interp,
-        ),
-        interp,
+    return flash_decode_paged_pallas(
+        q, kpool, vpool, tables, lengths, layer,
+        work=work, window=window, interpret=interp,
     )

@@ -404,6 +404,49 @@ def test_paged_work_list_walks_live_blocks(lens, dead):
     )
 
 
+# the served cells' heads: (kv heads, query heads a kv head serves, width);
+# smollm-360m packs two tokens to a pool row, granite-8b one
+SERVED_HEADS = {"smollm-360m": (5, 3, 64), "granite-8b": (8, 4, 128)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize(
+    "lens,dead,window",
+    [
+        # a sink row at length 0, one key, a ragged row over two blocks, a
+        # full row over all three: first and later items of a row
+        ([0, 1, 200, 384], [1, 0, 0, 0], None),
+        # a window narrower than a block, over rows of one to three blocks
+        ([384, 130, 0, 257], [0, 0, 1, 0], 150),
+    ],
+    ids=["ragged-sink-full", "window"],
+)
+@pytest.mark.parametrize("heads", sorted(SERVED_HEADS))
+def test_paged_kernel_at_served_head_shapes(heads, lens, dead, window, dtype):
+    """The kernel at each served cell's head shapes and block of 128 tokens
+    (smollm's two token parities a pool row, granite's one), against the
+    XLA twin on the same stacked pools: every row, sink rows included, and
+    rows whose later items reuse the query laid out at their first."""
+    KV, G, d = SERVED_HEADS[heads]
+    B, bs, n_blk = len(lens), 128, 3
+    rng = np.random.default_rng(8)
+    q, kpool, vpool, _ = _paged_inputs(rng, B, KV, G, d, bs, n_blk, B * n_blk + 1)
+    assert kvcache.tokens_per_row(KV, d, bs) == (2 if d == 64 else 1)
+    q, kpool, vpool = (x.astype(dtype) for x in (q, kpool, vpool))
+    tables = _sink_tables(rng, dead, n_blk, B * n_blk + 1)
+    lengths = jnp.asarray(lens, jnp.int32)
+    got = decode_attention_paged(
+        q, kpool, vpool, tables, lengths, LAYER, window=window,
+        impl="pallas", interpret=True,
+    )
+    twin = decode_attention_paged_xla(
+        q, kpool, vpool, tables, lengths, LAYER, window=window
+    )
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(twin, np.float32), **_tol(dtype)
+    )
+
+
 # ---------------------------------------------------------- compile economy
 
 

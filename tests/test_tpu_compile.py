@@ -98,7 +98,7 @@ def _kernel_names(text: str) -> list[str]:
     """Instruction names of the Mosaic kernels in a compiled program: the
     text a profiler trace names each kernel's operation by."""
     return [
-        line.split(" = ", 1)[0].strip().lstrip("%").rsplit(".", 1)[0]
+        line.split(" = ", 1)[0].split()[-1].lstrip("%").rsplit(".", 1)[0]
         for line in text.splitlines()
         if 'custom_call_target="tpu_custom_call"' in line
     ]
@@ -142,6 +142,37 @@ def test_paged_decode_compiles(spec, sizes):
         spec((), jnp.int32),
     )
     assert _kernel_names(text) == ["decode_attention_paged"]
+
+
+@pytest.mark.parametrize("sizes", CELLS)
+def test_paged_decode_scratch_fits_vmem(sizes):
+    """The paged kernel's VMEM scratch at each cell's heads: the
+    block-diagonal query and the accumulator, ``(r*KV*G, r*KV*d)`` each,
+    with the running max and normalizer, padded to the chip's tiles, fit
+    with the double-buffered K/V blocks in the v5e's default 16 MiB of
+    scoped VMEM."""
+    from repro.kernels.flash_attention.decode_attention import _decode_scratch
+    from repro.serve.kvcache import tokens_per_row
+
+    kv, g, d = _heads(sizes)
+    block = sizes["serve"]["block_size"]
+    r = tokens_per_row(kv, d, block)
+    q = jax.ShapeDtypeStruct((1, kv, g, d), jnp.bfloat16)
+    scratch = _decode_scratch(q, r)
+    assert [tuple(x.shape) for x in scratch] == [
+        (r * kv * g, r * kv * d), (r * kv * g, 1), (r * kv * g, 1),
+        (r * kv * g, r * kv * d),
+    ]
+
+    def tiled(shape, dtype):
+        rows = 8 * 4 // jnp.dtype(dtype).itemsize   # (8, 128) 32-bit words
+        return (-(-shape[0] // rows) * rows) * (-(-shape[1] // 128) * 128) * (
+            jnp.dtype(dtype).itemsize
+        )
+
+    used = sum(tiled(x.shape, x.dtype) for x in scratch)
+    kv_blocks = 2 * 2 * tiled((block // r, r * kv * d), jnp.bfloat16)
+    assert used + kv_blocks < 16 * 2**20, (used, kv_blocks)
 
 
 def test_engine_decode_program_names_its_kernel(one_chip, monkeypatch):
